@@ -44,14 +44,24 @@ table.  Prints ``name,us_per_call,derived`` CSV lines per the contract.
 Besides the CSV lines on stdout, every run writes ``BENCH_service.json``
 (name -> {us_per_call, derived}) so CI and future PRs can diff the perf
 trajectory machine-readably.
+
+Each bench module runs in a child process of its own, and this parent
+never imports JAX: a bench that brings up an accelerator holds it only
+for its own run, and no pod worker is ever forked from a process that
+holds one.
 """
 from __future__ import annotations
 
 import importlib
 import json
 import os
+import subprocess
 import sys
+import tempfile
 import time
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[1]
 
 MODULES = [
     "benchmarks.bench_cases",
@@ -92,7 +102,40 @@ def lines_to_json(lines) -> dict:
     return out
 
 
+def run_child(modname: str, out_path: str) -> None:
+    """Child side: run one bench module, write its lines (and the error
+    that stopped it, if any) to ``out_path`` as JSON."""
+    lines: list = []
+    error = None
+    try:
+        importlib.import_module(modname).run(lines)
+    except Exception as e:  # noqa: BLE001
+        error = repr(e)
+    with open(out_path, "w") as f:
+        json.dump({"lines": [str(l) for l in lines], "error": error}, f)
+
+
+def run_module(modname: str) -> tuple:
+    """Run one bench module in a child process; return its lines and
+    the error that stopped it (None when it finished)."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(ROOT)] + [p for p in [env.get("PYTHONPATH")] if p])
+    with tempfile.TemporaryDirectory() as tmp:
+        out_path = os.path.join(tmp, "lines.json")
+        rc = subprocess.call([sys.executable, "-m", "benchmarks.run",
+                              "--child", modname, out_path], env=env)
+        if not os.path.exists(out_path):
+            return [], f"bench child exited {rc} without a result"
+        with open(out_path) as f:
+            res = json.load(f)
+    return res["lines"], res["error"]
+
+
 def main() -> None:
+    if sys.argv[1:2] == ["--child"]:
+        run_child(*sys.argv[2:4])
+        return
     only = sys.argv[1:] or None
     known = {m.split(".")[-1] for m in MODULES}
     if only and not set(only) <= known:
@@ -108,8 +151,10 @@ def main() -> None:
         t0 = time.monotonic()
         before = len(lines)
         try:
-            mod = importlib.import_module(modname)
-            mod.run(lines)
+            child_lines, error = run_module(modname)
+            lines.extend(child_lines)
+            if error is not None:
+                raise RuntimeError(error)
             # a bench that "passes" while emitting no measurements is a
             # silently-dead gate: the artifact diff would show nothing
             # regressed because nothing was measured

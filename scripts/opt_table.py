@@ -3,9 +3,11 @@ import json
 from pathlib import Path
 
 from repro import configs
-from repro.launch.mesh import CHIPS_PER_POD, HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import (CHIPS_PER_POD, PRODUCTION_DEVICE_KIND,
+                               device_peaks)
 from repro.models.config import SHAPES
 
+PEAKS = device_peaks(PRODUCTION_DEVICE_KIND)
 R = Path(__file__).resolve().parents[1] / "results" / "dryrun"
 
 OPT_VARIANT = {a: "chunked_attn" for a in configs.ASSIGNED_ARCHS}
@@ -15,9 +17,9 @@ OPT_VARIANT["mamba2-370m"] = "baseline"   # attention-free: variant is a no-op
 
 
 def terms(rec):
-    return (rec["flops_per_device"] / PEAK_FLOPS_BF16,
-            rec["bytes_per_device"] / HBM_BW,
-            rec["collective_bytes_total"] / ICI_BW)
+    return (rec["flops_per_device"] / PEAKS.flops_bf16,
+            rec["bytes_per_device"] / PEAKS.hbm_bw,
+            rec["collective_bytes_total"] / PEAKS.ici_bw)
 
 
 def main():
@@ -39,7 +41,7 @@ def main():
         cfg = configs.get(arch)
         mf = 6.0 * cfg.param_count(active_only=cfg.is_moe) * \
             sh.global_batch * sh.seq_len
-        frac = mf / (CHIPS_PER_POD * PEAK_FLOPS_BF16) / ob * 100
+        frac = mf / (CHIPS_PER_POD * PEAKS.flops_bf16) / ob * 100
         print(f"{arch:20s} {v:14s} {bb:11.2f} {ob:10.2f} "
               f"{bb/ob:6.1f}x {frac:7.2f}")
 

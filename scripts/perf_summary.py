@@ -2,9 +2,11 @@
 import json
 from pathlib import Path
 
-from repro.launch.mesh import CHIPS_PER_POD, HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import (CHIPS_PER_POD, PRODUCTION_DEVICE_KIND,
+                               device_peaks)
 from repro import configs
 
+PEAKS = device_peaks(PRODUCTION_DEVICE_KIND)
 R = Path(__file__).resolve().parents[1] / "results" / "dryrun"
 
 CELLS = {
@@ -36,7 +38,7 @@ def model_flops(tag: str) -> float:
 def main():
     for title, (tag, variants) in CELLS.items():
         mf = model_flops(tag)
-        ideal = mf / (CHIPS_PER_POD * PEAK_FLOPS_BF16)
+        ideal = mf / (CHIPS_PER_POD * PEAKS.flops_bf16)
         print(f"\n### {title}   MODEL_FLOPS={mf:.3e}, ideal={ideal:.4f}s")
         print(f"{'variant':26s} {'compute_s':>10s} {'memory_s':>10s} "
               f"{'coll_s':>9s} {'bound_s':>10s} {'roofline%':>9s} {'useful':>7s}")
@@ -47,9 +49,9 @@ def main():
                 print(f"{v:26s} (missing)")
                 continue
             r = json.loads(p.read_text())
-            comp = r["flops_per_device"] / PEAK_FLOPS_BF16
-            mem = r["bytes_per_device"] / HBM_BW
-            coll = r["collective_bytes_total"] / ICI_BW
+            comp = r["flops_per_device"] / PEAKS.flops_bf16
+            mem = r["bytes_per_device"] / PEAKS.hbm_bw
+            coll = r["collective_bytes_total"] / PEAKS.ici_bw
             bound = max(comp, mem, coll)
             if base_bound is None:
                 base_bound = bound

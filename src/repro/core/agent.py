@@ -186,6 +186,17 @@ class NodeAgent:
         self.uploads += sent
         return sent
 
+    def counters(self) -> Dict[str, int]:
+        """Upload-path counters; ``upload_failures`` counts flushes the
+        service refused (their profiles were re-buffered, not lost)."""
+        return {"uploads": self.uploads,
+                "upload_failures": self.upload_failures,
+                "dropped": self.dropped,
+                "encoded_uploads": self.encoded_uploads,
+                "bytes_uploaded": self.bytes_uploaded,
+                "session_resyncs": self.session_resyncs,
+                "buffered": len(self._buffer)}
+
     # -- real-profiling lifecycle ------------------------------------------------
     def start(self) -> None:
         self.sampler.start()

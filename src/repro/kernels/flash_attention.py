@@ -9,7 +9,8 @@ q/k/v tiles stream HBM->VMEM per BlockSpec.
 
 Supports causal masking, sliding windows (Mixtral SWA) and GQA (kv head =
 q head // group) directly in the index maps — no KV repetition in HBM.
-Validated in interpret mode against ref.flash_attention_ref.
+Validated in interpret mode against ref.flash_attention_ref; forward only
+(no backward rule, so training uses the jnp attention paths).
 """
 from __future__ import annotations
 
@@ -71,7 +72,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_fwd(q, k, v, *, causal: bool = True,
                         sliding_window: int = 0, scale: float | None = None,
                         block_q: int = 128, block_k: int = 128,
-                        interpret: bool = True):
+                        interpret: bool):
     """q: (B, Hq, S, D); k/v: (B, Hkv, S, D) -> (B, Hq, S, D)."""
     b, hq, s, d = q.shape
     hkv = k.shape[1]

@@ -1,14 +1,14 @@
 """Public jit'd kernel entry points.
 
-Models call these; dispatch selects the Pallas kernel (TPU target,
-interpret-mode on CPU) or the pure-jnp oracle.  ``interpret`` defaults to
-True because this container is CPU-only; on a real TPU deployment it flips
-to False via REPRO_PALLAS_INTERPRET=0.
+Models call these; each runs its Pallas kernel, compiled on a TPU and
+through the Pallas interpreter on the CPU backend (the tests).  The
+choice is made at trace time from ``jax.default_backend()``; any other
+backend raises rather than silently interpreting.  The pure-jnp oracles
+are re-exported for the tests and the non-Pallas model paths.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +18,17 @@ from repro.kernels import rmsnorm as _rn
 from repro.kernels import ref as _ref
 from repro.kernels import ssd as _ssd
 
-_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+
+def pallas_interpret() -> bool:
+    """True on the CPU backend (interpret), False on TPU (compile)."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels compile for 'tpu' or interpret on 'cpu'; the "
+        f"default backend is {backend!r}")
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "sliding_window"))
@@ -27,7 +37,7 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True,
     """(B, Hq, S, D) layout."""
     return _fa.flash_attention_fwd(q, k, v, causal=causal,
                                    sliding_window=sliding_window,
-                                   interpret=_INTERPRET)
+                                   interpret=pallas_interpret())
 
 
 def flash_attention(q, k, v, *, causal: bool = True, sliding_window: int = 0):
@@ -42,14 +52,14 @@ def flash_attention(q, k, v, *, causal: bool = True, sliding_window: int = 0):
 
 @jax.jit
 def ssd_chunk(x, dt, A, B, C):
-    return _ssd.ssd_chunk_fwd(x, dt, A, B, C, interpret=_INTERPRET)
+    return _ssd.ssd_chunk_fwd(x, dt, A, B, C, interpret=pallas_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("eps",))
 def rmsnorm(x, weight, eps: float = 1e-6):
     shape = x.shape
     out = _rn.rmsnorm_fwd(x.reshape(-1, shape[-1]), weight,
-                          eps=eps, interpret=_INTERPRET)
+                          eps=eps, interpret=pallas_interpret())
     return out.reshape(shape)
 
 

@@ -32,34 +32,34 @@ def flash_attention_ref(q, k, v, *, causal: bool = True,
 
 
 def ssd_chunk_ref(x, dt, A, B, C):
-    """Chunk-local SSD terms (the Pallas kernel's contract).
+    """Chunk-local SSD terms (the Pallas kernel's contract), head-major.
 
-    x: (b, nc, l, h, p); dt: (b, nc, l, h); A: (h,); B, C: (b, nc, l, n)
-    Returns (y_diag (b,nc,l,h,p), states (b,nc,h,p,n), chunk_decay (b,nc,h),
-             in_decay (b,nc,h,l)).
+    x: (b, nc, h, l, p); dt: (b, nc, h, l); A: (h,); B, C: (b, nc, l, n)
+    Returns (y_diag (b,nc,h,l,p), states (b,nc,h,p,n), in_decay (b,nc,h,l));
+    the chunk decay is ``in_decay[..., -1]``.
     """
     f32 = jnp.float32
     xc, dtc = x.astype(f32), dt.astype(f32)
     Bc, Cc = B.astype(f32), C.astype(f32)
-    dA = dtc * A.astype(f32)                       # (b,nc,l,h)
-    dA_hl = jnp.moveaxis(dA, -1, -2)               # (b,nc,h,l)
-    dA_cum = jnp.cumsum(dA_hl, axis=-1)
+    dA = dtc * A.astype(f32)[:, None]              # (b,nc,h,l)
+    dA_cum = jnp.cumsum(dA, axis=-1)
 
     L = dA_cum[..., :, None] - dA_cum[..., None, :]
-    l_idx = jnp.arange(x.shape[2])
+    l_idx = jnp.arange(x.shape[3])
     tri = l_idx[:, None] >= l_idx[None, :]
-    L = jnp.where(tri, jnp.exp(L), 0.0)            # (b,nc,h,l,l)
+    # mask before exp: above the diagonal L is positive and can overflow,
+    # and exp(inf) * 0 would turn the gradient into NaN
+    L = jnp.exp(jnp.where(tri, L, -jnp.inf))       # (b,nc,h,l,l)
 
     scores = jnp.einsum("bcln,bcmn->bclm", Cc, Bc)
     gated = L * scores[:, :, None, :, :]           # (b,nc,h,l,m)
-    y_diag = jnp.einsum("bchlm,bcmh,bcmhp->bclhp", gated, dtc, xc)
+    y_diag = jnp.einsum("bchlm,bchm,bchmp->bchlp", gated, dtc, xc)
 
     decay_to_end = jnp.exp(dA_cum[..., -1:] - dA_cum)
-    states = jnp.einsum("bcln,bchl,bclh,bclhp->bchpn", Bc, decay_to_end,
+    states = jnp.einsum("bcln,bchl,bchl,bchlp->bchpn", Bc, decay_to_end,
                         dtc, xc)
-    chunk_decay = jnp.exp(dA_cum[..., -1])
     in_decay = jnp.exp(dA_cum)
-    return (y_diag.astype(x.dtype), states, chunk_decay, in_decay)
+    return (y_diag.astype(x.dtype), states, in_decay)
 
 
 def rmsnorm_ref(x, weight, eps: float = 1e-6):

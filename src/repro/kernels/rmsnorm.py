@@ -21,7 +21,7 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
 
 
 def rmsnorm_fwd(x, weight, *, eps: float = 1e-6, block_rows: int = 256,
-                interpret: bool = True):
+                interpret: bool):
     """x: (rows, d) (callers flatten batch dims); weight: (d,)."""
     rows, d = x.shape
     br = min(block_rows, rows)

@@ -1,9 +1,12 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-# NOTE: the two lines above MUST run before any other import (jax locks the
-# device count on first init).  This module is the ONLY place the 512
-# placeholder devices exist; tests/benches see the real single CPU device.
+# NOTE: the lines above MUST run before any other import (jax locks the
+# device count and the backend on first init).  This module is the ONLY
+# place the 512 placeholder devices exist; tests/benches see the real
+# single CPU device.  It is a placeholder-device tool, pinned to the CPU so
+# that it never takes an accelerator from the process that owns it.
 
 import argparse          # noqa: E402
 import dataclasses       # noqa: E402
@@ -180,6 +183,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     record = {
         "arch": arch, "shape": shape_name, "variant": variant,
         "multi_pod": multi_pod, "devices": int(n_devices),
+        "device_kind": mesh_lib.PRODUCTION_DEVICE_KIND,
         "kind": shape.kind,
         "seq_len": shape.seq_len, "global_batch": shape.global_batch,
         "lower_s": round(t_lower, 2), "compile_s": round(t_compile, 2),

@@ -33,9 +33,11 @@ def main() -> None:
 
     from repro import configs
     from repro.core.agent import AgentConfig, NodeAgent
+    from repro.launch.compile_cache import use_compile_cache
     from repro.models import build_model
     from repro.train import make_serve_step
 
+    use_compile_cache()
     cfg = dataclasses.replace(configs.tiny(args.arch),
                               param_dtype="float32",
                               compute_dtype="float32")

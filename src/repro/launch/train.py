@@ -18,9 +18,12 @@ import argparse
 import dataclasses
 import json
 import sys
+from typing import List, Optional
 
 
-def main() -> None:
+def main(argv: Optional[List[str]] = None):
+    """Run the launcher on ``argv`` (default: the command line) and return
+    the ``LoopResult`` of the training run."""
     ap = argparse.ArgumentParser(description="repro training launcher")
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=100)
@@ -37,7 +40,7 @@ def main() -> None:
     ap.add_argument("--no-observability", action="store_true")
     ap.add_argument("--sampling-rate", type=float, default=0.10)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.lower_only:
         # Re-exec through dryrun so the 512-device XLA flag is set before
@@ -49,10 +52,12 @@ def main() -> None:
 
     from repro import configs
     from repro.core.service import CentralService
+    from repro.launch.compile_cache import use_compile_cache
     from repro.data import DataPipeline, SyntheticCorpus
     from repro.models import build_model
     from repro.train.loop import LoopConfig, train_loop
 
+    use_compile_cache()
     cfg = configs.get(args.arch) if args.full else configs.tiny(args.arch)
     if not args.full:
         cfg = dataclasses.replace(cfg, param_dtype="float32")
@@ -105,6 +110,7 @@ def main() -> None:
         print(f"[train] observability: {service.ingested} profiles ingested, "
               f"{len(service.events)} diagnostic events "
               f"{json.dumps(service.event_counts())}")
+    return res
 
 
 if __name__ == "__main__":

@@ -67,7 +67,9 @@ class ModelConfig:
     compute_dtype: str = "bfloat16"
     remat: str = "dots"        # none | dots | full
     scan_layers: bool = True
-    use_pallas: bool = False   # Pallas kernels (TPU target; CPU uses jnp ref)
+    # Pallas kernels: compiled on TPU, interpreted on CPU.  Flash attention
+    # is forward-only, so a model that trains keeps this off.
+    use_pallas: bool = False
     attention_impl: str = "ref"  # ref (materialized) | chunked (flash-style)
     ce_impl: str = "ref"         # ref | chunked (blockwise logits+CE)
     ce_block_tokens: int = 512

@@ -2,8 +2,9 @@
 
 Reference math follows arXiv:2405.21060 (listing 1), with the inter-chunk
 recurrence expressed as a ``lax.scan`` (TPU-friendly) instead of a second
-segsum.  The chunk-local quadratic part is the Pallas-kernel target
-(repro.kernels.ssd); this module is the pure-jnp oracle and the dry-run path.
+segsum.  The chunk-local quadratic part comes from the Pallas kernel
+(repro.kernels.ssd) or its pure-jnp oracle (repro.kernels.ref), which is
+also the dry-run path.
 
 Shapes: x (B, S, H, P) heads x head_dim; A (H,); B/C (B, S, N) (ngroups=1);
 dt (B, S, H).  State: (B, H, P, N).
@@ -24,17 +25,6 @@ from repro.models import layers
 # ---------------------------------------------------------------------------
 
 
-def segsum(a):
-    """(..., L) -> (..., L, L) lower-triangular segment sums: out[i,j] =
-    sum(a[j+1..i]) for j < i, 0 on diagonal, -inf above."""
-    L = a.shape[-1]
-    cs = jnp.cumsum(a, axis=-1)
-    out = cs[..., :, None] - cs[..., None, :]
-    i = jnp.arange(L)[:, None]
-    j = jnp.arange(L)[None, :]
-    return jnp.where(j <= i, out, -jnp.inf)
-
-
 def ssd_chunked(x, dt, A, B, C, chunk: int,
                 initial_state=None,
                 use_pallas: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -44,8 +34,8 @@ def ssd_chunked(x, dt, A, B, C, chunk: int,
     B, C: (b, s, n)   state: (b, h, p, n)
 
     ``use_pallas`` routes the chunk-local quadratic term through the Pallas
-    TPU kernel (repro.kernels.ssd); the inter-chunk recurrence stays a
-    lax.scan either way.
+    TPU kernel (repro.kernels.ssd) instead of its jnp oracle; the
+    inter-chunk recurrence stays a lax.scan either way.
     """
     b, s, h, p = x.shape
     n = B.shape[-1]
@@ -53,36 +43,20 @@ def ssd_chunked(x, dt, A, B, C, chunk: int,
     assert s % chunk == 0, f"seq {s} not divisible by chunk {chunk}"
 
     f32 = jnp.float32
-    xc = x.reshape(b, nc, chunk, h, p).astype(f32)
-    dtc = dt.reshape(b, nc, chunk, h).astype(f32)
+    # head-major chunks: the chunk-term contract of repro.kernels
+    xc = jnp.moveaxis(x.reshape(b, nc, chunk, h, p), 3, 2).astype(f32)
+    dtc = jnp.moveaxis(dt.reshape(b, nc, chunk, h), 3, 2).astype(f32)
     Bc = B.reshape(b, nc, chunk, n).astype(f32)
     Cc = C.reshape(b, nc, chunk, n).astype(f32)
 
-    if use_pallas:
-        from repro.kernels import ops as kernel_ops
-        y_diag, states, chunk_decay, dA_cum_exp = kernel_ops.ssd_chunk(
-            xc, dtc, A, Bc, Cc)
-        y_diag = y_diag.astype(f32)
-        in_decay_pallas = dA_cum_exp                    # (b,nc,h,l) = exp(cum)
-        dA_cum = jnp.log(jnp.maximum(in_decay_pallas, 1e-38))
-    else:
-        dA = dtc * A.astype(f32)                       # (b,nc,l,h) log-decay
-        dA_hl = jnp.moveaxis(dA, -1, -2)               # (b,nc,h,l)
-        dA_cum = jnp.cumsum(dA_hl, axis=-1)            # (b,nc,h,l)
-
-        # ---- intra-chunk (quadratic attention-like) term ------------------
-        L = jnp.exp(segsum(dA_hl))                     # (b,nc,h,l,l)
-        scores = jnp.einsum("bcln,bcmn->bclm", Cc, Bc)  # (b,nc,l,m)
-        gated = scores[:, :, None] * L                 # (b,nc,h,l,m)
-        y_diag = jnp.einsum("bchlm,bcmh,bcmhp->bclhp", gated, dtc, xc)
-
-        # ---- chunk summary states -----------------------------------------
-        decay_to_end = jnp.exp(dA_cum[..., -1:] - dA_cum)   # (b,nc,h,l)
-        states = jnp.einsum("bcln,bchl,bclh,bclhp->bchpn",
-                            Bc, decay_to_end, dtc, xc)      # (b,nc,h,p,n)
+    # ---- intra-chunk (quadratic attention-like) term + chunk states ------
+    from repro.kernels import ops as kernel_ops
+    chunk_terms = kernel_ops.ssd_chunk if use_pallas else kernel_ops.ssd_chunk_ref
+    y_diag, states, in_decay = chunk_terms(xc, dtc, A, Bc, Cc)
+    # y_diag (b,nc,h,l,p); states (b,nc,h,p,n); in_decay (b,nc,h,l)
 
     # ---- inter-chunk recurrence (scan over chunks) -----------------------
-    chunk_decay = jnp.exp(dA_cum[..., -1])              # (b,nc,h)
+    chunk_decay = in_decay[..., -1]                     # (b,nc,h)
     if initial_state is None:
         initial_state = jnp.zeros((b, h, p, n), dtype=f32)
     else:
@@ -98,10 +72,9 @@ def ssd_chunked(x, dt, A, B, C, chunk: int,
     entry_states = jnp.moveaxis(entry_states, 0, 1)     # (b,nc,h,p,n)
 
     # ---- off-diagonal contribution from carried state --------------------
-    in_decay = jnp.exp(dA_cum)                          # decay from chunk start
-    y_off = jnp.einsum("bcln,bchl,bchpn->bclhp", Cc, in_decay, entry_states)
+    y_off = jnp.einsum("bcln,bchl,bchpn->bchlp", Cc, in_decay, entry_states)
 
-    y = (y_diag + y_off).reshape(b, s, h, p)
+    y = jnp.moveaxis(y_diag.astype(f32) + y_off, 2, 3).reshape(b, s, h, p)
     return y.astype(x.dtype), final_state
 
 

@@ -4,6 +4,9 @@
     memory     = HLO_bytes   / (chips * HBM_bw)
     collective = coll_bytes  / (chips * link_bw)
 
+The peaks come from ``launch.mesh.DEVICE_PEAKS`` for the record's
+``device_kind`` (the chip the dry run's mesh models).
+
 cost_analysis() FLOPs/bytes from the compiled per-device program are
 multiplied back to global by ``devices`` (XLA reports the per-device
 partition); collective bytes come from the HLO parse (roofline.hlo).
@@ -16,7 +19,7 @@ import json
 from pathlib import Path
 from typing import Dict, List, Optional
 
-from repro.launch.mesh import CHIPS_PER_POD, HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import CHIPS_PER_POD, device_peaks
 
 
 @dataclasses.dataclass
@@ -47,14 +50,15 @@ def analyze_record(rec: dict, chips: int = CHIPS_PER_POD) -> Optional[RooflineRo
     flops_per_dev = cost.get("flops", 0.0)
     bytes_per_dev = cost.get("bytes accessed", 0.0)
     devices = rec.get("devices", chips)
+    peaks = device_peaks(rec.get("device_kind"))
 
     hlo_flops_global = flops_per_dev * devices
     hlo_bytes_global = bytes_per_dev * devices
     coll_bytes_global = rec.get("collective_bytes_total", 0) * devices
 
-    compute_s = hlo_flops_global / (chips * PEAK_FLOPS_BF16)
-    memory_s = hlo_bytes_global / (chips * HBM_BW)
-    collective_s = coll_bytes_global / (chips * ICI_BW)
+    compute_s = hlo_flops_global / (chips * peaks.flops_bf16)
+    memory_s = hlo_bytes_global / (chips * peaks.hbm_bw)
+    collective_s = coll_bytes_global / (chips * peaks.ici_bw)
 
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
@@ -68,7 +72,7 @@ def analyze_record(rec: dict, chips: int = CHIPS_PER_POD) -> Optional[RooflineRo
     useful = model_flops / hlo_flops_global if hlo_flops_global else 0.0
     # roofline fraction: useful work per second at the bound, vs peak
     bound = max(terms.values())
-    roofline_fraction = (model_flops / (chips * PEAK_FLOPS_BF16) / bound
+    roofline_fraction = (model_flops / (chips * peaks.flops_bf16) / bound
                          if bound > 0 else 0.0)
 
     return RooflineRow(

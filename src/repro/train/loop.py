@@ -47,6 +47,12 @@ class LoopResult:
     steps_per_s: float
     final_step: int
     diagnostics: List[Any]
+    # host-clock seconds per step, each ending when the loss reaches the
+    # host (the first one includes the step's compile)
+    step_times: List[float]
+    service_cycles: int          # service.process() calls during the run
+    profiles_ingested: int       # service.ingested after the final flush
+    agent: Dict[str, int]        # NodeAgent.counters() after the final flush
 
 
 def train_loop(model: Model, pipeline: DataPipeline, cfg: LoopConfig,
@@ -71,7 +77,7 @@ def train_loop(model: Model, pipeline: DataPipeline, cfg: LoopConfig,
             start_step = manifest["step"]
             pipeline.cursor = manifest["cursor"]
     if state is None:
-        state = init_train_state(model, key)
+        state = jax.jit(init_train_state, static_argnums=0)(model, key)
 
     # -- observability agent ---------------------------------------------------
     agent = None
@@ -89,7 +95,9 @@ def train_loop(model: Model, pipeline: DataPipeline, cfg: LoopConfig,
 
     pipeline.start()
     losses: List[float] = []
+    step_times: List[float] = []
     diagnostics: List[Any] = []
+    service_cycles = 0
     t_start = time.monotonic()
     try:
         for step in range(start_step, cfg.total_steps):
@@ -101,6 +109,7 @@ def train_loop(model: Model, pipeline: DataPipeline, cfg: LoopConfig,
             loss = float(metrics["loss"])          # blocks on completion
             t1 = time.monotonic()
             losses.append(loss)
+            step_times.append(t1 - t0)
 
             if agent is not None:
                 # step boundary = the collective boundary on this substrate
@@ -117,6 +126,7 @@ def train_loop(model: Model, pipeline: DataPipeline, cfg: LoopConfig,
                     agent.flush()
                     if service is not None:
                         diagnostics.extend(service.process())
+                        service_cycles += 1
 
             if ckpt and (step + 1) % cfg.checkpoint_every == 0:
                 ckpt.save(step + 1, state, cursor=pipeline.cursor)
@@ -135,5 +145,9 @@ def train_loop(model: Model, pipeline: DataPipeline, cfg: LoopConfig,
 
     elapsed = time.monotonic() - t_start
     n = max(cfg.total_steps - start_step, 1)
-    return LoopResult(losses=losses, steps_per_s=n / elapsed,
-                      final_step=cfg.total_steps, diagnostics=diagnostics)
+    return LoopResult(
+        losses=losses, steps_per_s=n / elapsed, final_step=cfg.total_steps,
+        diagnostics=diagnostics, step_times=step_times,
+        service_cycles=service_cycles,
+        profiles_ingested=service.ingested if service is not None else 0,
+        agent=agent.counters() if agent is not None else {})

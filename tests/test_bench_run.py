@@ -1,9 +1,12 @@
 """benchmarks/run.py harness contract: a raising bench module must exit
 non-zero and must mark the failure inside the emitted JSON, so CI can
-never upload a partial trajectory as green."""
+never upload a partial trajectory as green.  Every bench runs in a child
+process, and the harness itself never imports JAX."""
 import json
+import os
+import subprocess
 import sys
-import types
+import textwrap
 from pathlib import Path
 
 import pytest
@@ -13,34 +16,31 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 import benchmarks.run as runmod  # noqa: E402
 
 
-def _module(name: str, run):
-    mod = types.ModuleType(name)
-    mod.run = run
-    return mod
-
-
 def _patch(monkeypatch, tmp_path, modules):
-    names = []
-    for name, fn in modules:
-        full = f"benchmarks.{name}"
-        monkeypatch.setitem(sys.modules, full, _module(full, fn))
-        names.append(full)
-    monkeypatch.setattr(runmod, "MODULES", names)
+    """Write each (name, source of ``run``) as a module the bench child
+    processes import from ``tmp_path``."""
+    for name, src in modules:
+        (tmp_path / f"{name}.py").write_text(textwrap.dedent(src))
+    monkeypatch.setenv("PYTHONPATH", str(tmp_path))
+    monkeypatch.setattr(runmod, "MODULES", [name for name, _ in modules])
     monkeypatch.setattr(runmod, "JSON_PATH", str(tmp_path / "bench.json"))
     monkeypatch.setattr(sys, "argv", ["run.py"])
     return tmp_path / "bench.json"
 
 
-def test_run_exits_nonzero_when_a_module_raises(monkeypatch, tmp_path):
-    def ok(lines):
+OK = """
+    def run(lines):
         lines.append("ok_metric,2,fine")
+"""
 
-    def boom(lines):
+
+def test_run_exits_nonzero_when_a_module_raises(monkeypatch, tmp_path):
+    boom = """
+    def run(lines):
         lines.append("partial_metric,1,emitted-before-crash")
         raise RuntimeError("kaboom")
-
-    json_path = _patch(monkeypatch, tmp_path,
-                       [("_ok", ok), ("_boom", boom)])
+    """
+    json_path = _patch(monkeypatch, tmp_path, [("_ok", OK), ("_boom", boom)])
     with pytest.raises(SystemExit) as exc:
         runmod.main()
     assert exc.value.code == 1
@@ -52,14 +52,12 @@ def test_run_exits_nonzero_when_a_module_raises(monkeypatch, tmp_path):
     assert data["_boom_wall"]["derived"].startswith("FAILED")
     assert data["bench_run_failures"]["count"] == 1
     assert "_boom" in data["bench_run_failures"]["derived"]
+    assert "kaboom" in data["bench_run_failures"]["derived"]
 
 
 def test_run_exits_zero_and_marks_no_failures_when_green(monkeypatch,
                                                          tmp_path):
-    def ok(lines):
-        lines.append("ok_metric,2,fine")
-
-    json_path = _patch(monkeypatch, tmp_path, [("_ok", ok)])
+    json_path = _patch(monkeypatch, tmp_path, [("_ok", OK)])
     runmod.main()                       # no SystemExit
     data = json.loads(json_path.read_text())
     assert data["bench_run_failures"]["count"] == 0
@@ -67,8 +65,29 @@ def test_run_exits_zero_and_marks_no_failures_when_green(monkeypatch,
 
 
 def test_run_rejects_unknown_selection(monkeypatch, tmp_path):
-    _patch(monkeypatch, tmp_path, [("_ok", lambda lines: None)])
+    _patch(monkeypatch, tmp_path, [("_ok", OK)])
     monkeypatch.setattr(sys, "argv", ["run.py", "no_such_bench"])
     with pytest.raises(SystemExit) as exc:
         runmod.main()
     assert exc.value.code == 2
+
+
+def test_each_bench_runs_in_its_own_process(monkeypatch, tmp_path):
+    pid = """
+    import os
+    def run(lines):
+        lines.append(f"{__name__},{os.getpid()},child")
+    """
+    json_path = _patch(monkeypatch, tmp_path, [("_pid1", pid), ("_pid2", pid)])
+    runmod.main()
+    data = json.loads(json_path.read_text())
+    pids = {data["_pid1"]["us_per_call"], data["_pid2"]["us_per_call"]}
+    assert len(pids) == 2 and float(os.getpid()) not in pids
+
+
+def test_harness_parent_never_imports_jax():
+    root = Path(__file__).resolve().parents[1]
+    code = "import sys, benchmarks.run; print('jax' in sys.modules)"
+    out = subprocess.run([sys.executable, "-c", code], cwd=root,
+                         capture_output=True, text=True, check=True).stdout
+    assert out.strip() == "False"

@@ -107,7 +107,7 @@ def test_sharding_context_applies_spec():
     from jax.sharding import AbstractMesh
     from repro.parallel.context import sharding_context, constrain
     from repro.parallel.sharding import ShardingRules
-    mesh = AbstractMesh((("data", 1), ("model", 1)))
+    mesh = AbstractMesh((1, 1), ("data", "model"))
     rules = ShardingRules(seq_parallel=True)
 
     def f(x):

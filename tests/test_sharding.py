@@ -7,7 +7,7 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.parallel.sharding import ShardingRules, batch_pspec, logical_to_pspec
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
 RULES = ShardingRules()
 
 
@@ -82,7 +82,7 @@ def test_batch_replicated_when_indivisible():
 
 
 def test_multipod_batch_axes():
-    mesh3 = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+    mesh3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     rules = ShardingRules(pod_axis="pod")
     got = logical_to_pspec(("batch", None), (256, 4096), mesh3, rules)
     assert got == P(("pod", "data"), None)
