@@ -18,6 +18,7 @@ Modules map 1:1 to the paper's mechanisms:
   stitch        — Python↔native stack stitching (§4)
   samplers      — real in-process sampling profiler (overhead benchmark)
   agent         — node agent (collection, aggregation, upload)
+  spans         — named host spans on the profiler's clock
   scenarios     — pluggable scenario + diagnosis-rule registry (SOP
                   signatures, OS thresholds, fault bundles; docs are
                   generated from it)

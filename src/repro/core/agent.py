@@ -17,6 +17,7 @@ from repro.core.aggregate import StackAggregator
 from repro.core.collective.tracer import CollectiveTracer
 from repro.core.events import IterationProfile, ProfileBatch
 from repro.core.samplers import SamplingProfiler
+from repro.core.spans import span
 from repro.core.symbols.resolver import CentralResolver
 from repro.core.trace import (ColumnarBatch, ColumnarProfile, RemapCache,
                               TraceTables, WireEncoder, WireFormatError,
@@ -140,6 +141,11 @@ class NodeAgent:
         """
         with self._lock:
             batch, self._buffer = self._buffer, []
+        with span("sysom.agent.flush",
+                  step=batch[-1].iteration if batch else -1):
+            return self._upload(batch)
+
+    def _upload(self, batch) -> int:
         if self.service is None:
             with self._lock:
                 self._buffer = batch + self._buffer

@@ -69,6 +69,7 @@ from repro.core.query import (BlameRoot, DiagnosisQueryAPI, EventLog,
                               blame_roots_from)
 from repro.core.scenarios import (LEGACY_CATEGORIES, ScenarioRegistry,
                                   default_registry)
+from repro.core.spans import span
 from repro.core.straggler import StragglerAlert, StragglerDetector
 from repro.core.symbols.repo import SymbolRepository
 from repro.core.trace import (ColumnFlameGraph, ColumnarProfile, RemapCache,
@@ -529,6 +530,11 @@ class CentralService(DiagnosisQueryAPI):
             ev.detected_at = t0 + i * 1e-9
 
     def process(self) -> List[DiagnosticEvent]:
+        """One diagnosis cycle; its span carries the epoch it publishes."""
+        with span("sysom.service.process", epoch=self._epoch + 1):
+            return self._process()
+
+    def _process(self) -> List[DiagnosticEvent]:
         t0 = time.monotonic()
         new_events: List[DiagnosticEvent] = []
         flagged: set = set()

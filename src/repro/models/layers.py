@@ -146,6 +146,7 @@ def init_embedding(key, cfg: ModelConfig):
     return out
 
 
+@jax.named_scope("embed")
 def embed(params, tokens, cfg: ModelConfig):
     x = jnp.take(params["embedding"], tokens, axis=0)
     if cfg.name.startswith("gemma"):
@@ -159,6 +160,7 @@ def logits_head(params, x, cfg: ModelConfig):
     return x @ params["lm_head"]
 
 
+@jax.named_scope("head_loss")
 def lm_loss(params, hidden, labels, cfg) -> jnp.ndarray:
     """Mean next-token CE from final hidden states.
 

@@ -68,16 +68,19 @@ def init_params(key, cfg: ModelConfig):
 
 
 def _layer_forward(layer_params, x, cfg: ModelConfig, positions):
-    h = attention.attention(
-        layer_params["attn"],
-        layers.rms_norm(x, layer_params["attn_norm"], cfg.norm_eps),
-        cfg, positions)
+    with jax.named_scope("attention"):
+        h = attention.attention(
+            layer_params["attn"],
+            layers.rms_norm(x, layer_params["attn_norm"], cfg.norm_eps),
+            cfg, positions)
     x = x + h
-    normed = layers.rms_norm(x, layer_params["mlp_norm"], cfg.norm_eps)
-    if cfg.is_moe:
-        f, aux = moe.moe_ffn(layer_params["moe"], normed, cfg)
-    else:
-        f, aux = layers.mlp(layer_params["mlp"], normed, cfg), jnp.float32(0)
+    with jax.named_scope("mlp"):
+        normed = layers.rms_norm(x, layer_params["mlp_norm"], cfg.norm_eps)
+        if cfg.is_moe:
+            f, aux = moe.moe_ffn(layer_params["moe"], normed, cfg)
+        else:
+            f = layers.mlp(layer_params["mlp"], normed, cfg)
+            aux = jnp.float32(0)
     return x + f, aux
 
 

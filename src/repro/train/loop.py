@@ -5,6 +5,11 @@ with the SysOM-AI node agent attached: per-step collective events (host
 entry/exit timestamps around the blocking step, §3.2's library-boundary
 analog), the real sampling profiler (§5.1), periodic uploads to the central
 service, and a mitigation hook fed by the service's diagnoses.
+
+Each iteration is a ``train`` step annotation holding the spans
+``sysom.loop.next_batch``, ``dispatch``, ``step_wait``, ``loss_fetch`` and,
+with the agent on, ``observe`` (``repro.core.spans``), so a profiler trace
+of the job names what the host did between the device's steps.
 """
 from __future__ import annotations
 
@@ -19,6 +24,7 @@ from repro.checkpoint import AsyncCheckpointer, latest_step, load_checkpoint
 from repro.core.agent import AgentConfig, NodeAgent
 from repro.core.events import CollectiveEvent, IterationProfile
 from repro.core.service import CentralService
+from repro.core.spans import span
 from repro.data import DataPipeline
 from repro.models import Model
 from repro.optim import make_schedule
@@ -101,40 +107,50 @@ def train_loop(model: Model, pipeline: DataPipeline, cfg: LoopConfig,
     t_start = time.monotonic()
     try:
         for step in range(start_step, cfg.total_steps):
-            batch_np = next(pipeline)
-            batch = {k: jax.numpy.asarray(v) for k, v in batch_np.items()}
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                with span("sysom.loop.next_batch", step=step):
+                    batch_np = next(pipeline)
+                with span("sysom.loop.dispatch", step=step):
+                    batch = {k: jax.numpy.asarray(v)
+                             for k, v in batch_np.items()}
+                    t0 = time.monotonic()
+                    state, metrics = step_fn(state, batch)
+                # the step's outputs come from one execution: its counter
+                # is ready once the device has done the whole step
+                with span("sysom.loop.step_wait", step=step):
+                    jax.block_until_ready(state["step"])
+                with span("sysom.loop.loss_fetch", step=step):
+                    loss = float(metrics["loss"])
+                t1 = time.monotonic()
+                losses.append(loss)
+                step_times.append(t1 - t0)
 
-            t0 = time.monotonic()
-            state, metrics = step_fn(state, batch)
-            loss = float(metrics["loss"])          # blocks on completion
-            t1 = time.monotonic()
-            losses.append(loss)
-            step_times.append(t1 - t0)
+                if agent is not None:
+                    with span("sysom.loop.observe", step=step):
+                        # step boundary = the collective boundary on this
+                        # substrate
+                        ev = agent.tracer.record_collective(
+                            group_id, "AllReduce", entry=t0, exit=t1,
+                            nbytes=sum(int(np.prod(l.shape)) * 2 for l in
+                                       jax.tree.leaves(state["params"])))
+                        prof = IterationProfile(
+                            rank=rank, iteration=step, group_id=group_id,
+                            iter_time=t1 - t0, cpu_samples=[],
+                            kernel_events=[], collectives=[ev])
+                        agent.submit(prof)
+                        if (step + 1) % 10 == 0:
+                            agent.flush()
+                            if service is not None:
+                                diagnostics.extend(service.process())
+                                service_cycles += 1
 
-            if agent is not None:
-                # step boundary = the collective boundary on this substrate
-                ev = agent.tracer.record_collective(
-                    group_id, "AllReduce", entry=t0, exit=t1,
-                    nbytes=sum(int(np.prod(l.shape)) * 2 for l in
-                               jax.tree.leaves(state["params"])))
-                prof = IterationProfile(
-                    rank=rank, iteration=step, group_id=group_id,
-                    iter_time=t1 - t0, cpu_samples=[], kernel_events=[],
-                    collectives=[ev])
-                agent.submit(prof)
-                if (step + 1) % 10 == 0:
-                    agent.flush()
-                    if service is not None:
-                        diagnostics.extend(service.process())
-                        service_cycles += 1
+                if ckpt and (step + 1) % cfg.checkpoint_every == 0:
+                    ckpt.save(step + 1, state, cursor=pipeline.cursor)
 
-            if ckpt and (step + 1) % cfg.checkpoint_every == 0:
-                ckpt.save(step + 1, state, cursor=pipeline.cursor)
-
-            if (step + 1) % cfg.log_every == 0:
-                dt = time.monotonic() - t_start
-                print(f"step {step+1}/{cfg.total_steps} loss={loss:.4f} "
-                      f"({(step+1-start_step)/dt:.2f} steps/s)")
+                if (step + 1) % cfg.log_every == 0:
+                    dt = time.monotonic() - t_start
+                    print(f"step {step+1}/{cfg.total_steps} loss={loss:.4f} "
+                          f"({(step+1-start_step)/dt:.2f} steps/s)")
     finally:
         pipeline.stop()
         if agent is not None:

@@ -85,10 +85,12 @@ def make_train_step(model: Model, schedule: Callable,
     def train_step(state, batch):
         (loss, metrics), grads = jax.value_and_grad(
             model.loss_fn, has_aux=True)(state["params"], batch)
-        grads, gnorm = clip_by_global_norm(grads, max_grad_norm)
-        lr = schedule(state["step"])
-        new_params, new_opt = adamw_update(
-            grads, state["opt"], state["params"], lr, state["step"], adamw_cfg)
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, max_grad_norm)
+            lr = schedule(state["step"])
+            new_params, new_opt = adamw_update(grads, state["opt"],
+                                               state["params"], lr,
+                                               state["step"], adamw_cfg)
         new_state = {"params": new_params, "opt": new_opt,
                      "step": state["step"] + 1}
         metrics = dict(metrics, grad_norm=gnorm, lr=lr)
