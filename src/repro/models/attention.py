@@ -1,8 +1,13 @@
 """Multi-head attention: GQA/MQA, sliding windows, qk-norm, KV-cache decode.
 
-The jnp path here is the reference/dry-run implementation; the Pallas flash
-kernel (repro.kernels.flash_attention) is the TPU-target hot path, selected
-via ``cfg.use_pallas``.
+``attention_impl="ref"`` is exact softmax attention.  Full-sequence causal
+self-attention with no sliding window, at a length the kernels' blocks
+divide, lowers on a TPU to the fused kernels of
+``repro.kernels.fused_attention`` (forward and backward, the scores kept
+in VMEM); every other call, and every other platform, materialises the
+scores with einsums, which are the fused kernels' oracle in the tests.
+``cfg.use_pallas`` selects the forward-only Pallas flash kernel
+(``repro.kernels.flash_attention``) instead.
 """
 from __future__ import annotations
 
@@ -11,6 +16,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import fused_attention
 from repro.models.config import ModelConfig
 from repro.models import layers
 
@@ -129,11 +135,35 @@ def _attention_chunked(q, k, v, cfg: ModelConfig, causal: bool,
     return out.astype(q.dtype)
 
 
+def _attention_materialized(q, k, v, cfg: ModelConfig,
+                            causal: bool) -> jnp.ndarray:
+    """Exact softmax attention over the whole (S, S) score matrix."""
+    b, s, nq, h = q.shape
+    qg = _group_query(q, cfg.num_kv_heads)          # (b,s,kv,g,h)
+    scores = jnp.einsum("bqkgh,bskh->bkgqs", qg, k).astype(jnp.float32)
+    scores = scores * (h ** -0.5)
+    qi = jnp.arange(s)[:, None]
+    kj = jnp.arange(s)[None, :]
+    mask = jnp.ones((s, s), dtype=bool)
+    if causal:
+        mask &= kj <= qi
+    if cfg.sliding_window:
+        mask &= kj > qi - cfg.sliding_window
+    scores = jnp.where(mask, scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    out = jnp.einsum("bkgqs,bskh->bqkgh", probs, v)
+    return out.reshape(b, s, nq, h)
+
+
+def _attention_fused(q, k, v):
+    with jax.named_scope("flash"):
+        return fused_attention.causal_attention(q, k, v)
+
+
 def attention(params, x, cfg: ModelConfig, positions,
               causal: bool = True) -> jnp.ndarray:
-    """Reference attention for training/prefill; (B, S, d) -> (B, S, d)."""
-    b, s, _ = x.shape
-    h = cfg.resolved_head_dim
+    """Attention for training/prefill; (B, S, d) -> (B, S, d)."""
+    s = x.shape[1]
     q, k, v = _project_qkv(params, x, cfg, positions)
 
     if cfg.use_pallas:
@@ -142,21 +172,16 @@ def attention(params, x, cfg: ModelConfig, positions,
             q, k, v, causal=causal, sliding_window=cfg.sliding_window)
     elif cfg.attention_impl == "chunked":
         out = _attention_chunked(q, k, v, cfg, causal)
+    elif causal and not cfg.sliding_window and fused_attention.fits(
+            s, cfg.resolved_head_dim):
+        # the platform the program lowers for picks the branch: the other
+        # one is traced but never lowered
+        out = jax.lax.platform_dependent(
+            q, k, v, tpu=_attention_fused,
+            default=lambda q, k, v: _attention_materialized(q, k, v, cfg,
+                                                            True))
     else:
-        qg = _group_query(q, cfg.num_kv_heads)          # (b,s,kv,g,h)
-        scores = jnp.einsum("bqkgh,bskh->bkgqs", qg, k).astype(jnp.float32)
-        scores = scores * (h ** -0.5)
-        qi = jnp.arange(s)[:, None]
-        kj = jnp.arange(s)[None, :]
-        mask = jnp.ones((s, s), dtype=bool)
-        if causal:
-            mask &= kj <= qi
-        if cfg.sliding_window:
-            mask &= kj > qi - cfg.sliding_window
-        scores = jnp.where(mask, scores, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-        out = jnp.einsum("bkgqs,bskh->bqkgh", probs, v)
-        out = out.reshape(b, s, cfg.num_heads, h)
+        out = _attention_materialized(q, k, v, cfg, causal)
     return jnp.einsum("bsnh,nhd->bsd", out, params["wo"])
 
 

@@ -70,7 +70,10 @@ class ModelConfig:
     # Pallas kernels: compiled on TPU, interpreted on CPU.  Flash attention
     # is forward-only, so a model that trains keeps this off.
     use_pallas: bool = False
-    attention_impl: str = "ref"  # ref (materialized) | chunked (flash-style)
+    # ref: exact softmax attention, lowered on a TPU to the fused kernels
+    # (forward and backward) where the shape fits and materialised
+    # elsewhere | chunked: flash-style jnp scan over key blocks
+    attention_impl: str = "ref"
     ce_impl: str = "ref"         # ref | chunked (blockwise logits+CE)
     ce_block_tokens: int = 512
     vocab_pad_multiple: int = 128

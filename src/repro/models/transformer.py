@@ -13,6 +13,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import fused_attention
 from repro.models.config import ModelConfig
 from repro.models import attention, layers, moe
 
@@ -104,7 +105,13 @@ def _remat(fn, cfg: ModelConfig):
     if cfg.remat == "none":
         return fn
     if cfg.remat == "dots":
-        policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+        # the fused attention's inputs, output and log-sum-exp are kept
+        # too: the backward then runs neither its forward kernel nor the
+        # layout work before it again
+        policy = jax.checkpoint_policies.save_from_both_policies(
+            jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+            jax.checkpoint_policies.save_only_these_names(
+                fused_attention.RESIDUALS))
         return jax.checkpoint(fn, policy=policy)
     return jax.checkpoint(fn)
 

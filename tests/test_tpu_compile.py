@@ -21,6 +21,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 import chip_smoke  # noqa: E402
 from repro import configs  # noqa: E402
 from repro.kernels import flash_attention as fa  # noqa: E402
+from repro.kernels import fused_attention  # noqa: E402
 from repro.kernels import rmsnorm as rn  # noqa: E402
 from repro.kernels import ssd  # noqa: E402
 from repro.models import build_model  # noqa: E402
@@ -72,6 +73,25 @@ def test_flash_attention_compiles_at_qwen2_widths(one_chip):
                              jax.ShapeDtypeStruct(k, jnp.bfloat16)]))
 
 
+def test_fused_attention_forward_and_backward_compile_at_qwen2_widths(
+        one_chip):
+    b, s, d = 4, 1024, 64
+    q, k = (b, s, 14, d), (b, s, 2, d)
+
+    def forward_and_backward(q, k, v, ct):
+        out, vjp = jax.vjp(fused_attention.causal_attention, q, k, v)
+        return out, vjp(ct)
+
+    text = _compile(forward_and_backward,
+                    *_on(one_chip, [jax.ShapeDtypeStruct(q, jnp.bfloat16),
+                                    jax.ShapeDtypeStruct(k, jnp.bfloat16),
+                                    jax.ShapeDtypeStruct(k, jnp.bfloat16),
+                                    jax.ShapeDtypeStruct(q, jnp.bfloat16)])
+                    ).as_text()
+    for kernel in ("splash_mqa_fwd", "splash_mqa_dq", "splash_mqa_dkv"):
+        assert kernel in text
+
+
 def test_rmsnorm_compiles_at_d896(one_chip):
     _compile(lambda x, w: rn.rmsnorm_fwd(x, w, interpret=False),
              *_on(one_chip, [jax.ShapeDtypeStruct((4096, 896), jnp.bfloat16),
@@ -115,8 +135,10 @@ def test_smoke_train_step_fits_one_chip(one_chip):
     schedule = make_schedule("cosine", peak_lr=3e-4, warmup_steps=5,
                              total_steps=chip_smoke.TRAIN_STEPS)
     step = jax.jit(make_train_step(model, schedule), donate_argnums=(0,))
-    mem = step.lower(_on(one_chip, state),
-                     _on(one_chip, batch)).compile().memory_analysis()
+    compiled = step.lower(_on(one_chip, state), _on(one_chip, batch)).compile()
+    # attention runs the fused kernels
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
     # the donated state aliases the new state, so arguments + temporaries
     # is the whole program's footprint
     assert mem.alias_size_in_bytes > 0.99 * mem.argument_size_in_bytes
