@@ -15,7 +15,9 @@ objects it hands in and through thin wrappers:
   the loss transfer, the agent and the service;
 - the loop's ``jax.jit`` of the train step is wrapped, so the harness sees
   the state that goes into the first four steps and the loss that each
-  step returns; the compiled program is the loop's own;
+  step returns; the compiled program is the loop's own; a traced run also
+  keeps the jitted step and its arguments' shapes, to name the trace's
+  device operations by the step's HLO once the window has closed;
 - ``NodeAgent.submit``/``flush``, ``SamplingProfiler._snapshot`` and the
   service's ``process`` are wrapped to time them and, in a traced run, to
   put a ``TraceAnnotation`` span on the profiler's clock.
@@ -44,9 +46,9 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from benchlib import flops, peaks, reftrain, tracereduce
+from benchlib import flops, peaks, programtrace, reftrain, tracereduce
 from benchlib.compileclock import CompileClock
-from benchlib.spec import ROOT, Cell, metric_reader
+from benchlib.spec import Cell, metric_reader
 
 SPANS = ("next_batch", "step_dispatch", "loss_sync", "agent_submit",
          "agent_flush", "sampler_snapshot", "service_process")
@@ -109,6 +111,9 @@ class Probe:
     sampler_cpu: List[Optional[float]] = dataclasses.field(
         default_factory=list)
     service: Any = None
+    # a traced run's jitted step and its arguments' shapes
+    step_fn: Any = None
+    step_args: Any = None
 
     # -- spans ------------------------------------------------------------
     @contextlib.contextmanager
@@ -172,6 +177,12 @@ class Probe:
             self.calls += 1
             if i == 0:
                 self.params0 = jax.device_get(state["params"])
+                if self.tracing:
+                    self.step_fn = jitted
+                    self.step_args = jax.tree.map(
+                        lambda x: jax.ShapeDtypeStruct(
+                            x.shape, x.dtype, sharding=x.sharding),
+                        (state, batch))
             elif i == 1:
                 paths = reftrain.leaf_paths(state["opt"]["m"])
                 self.grad_norms = {
@@ -274,7 +285,7 @@ def drive(cell: Cell, seed: int, seconds: float, tracing: bool) -> Probe:
     probe = Probe(warmup=traffic["setup_steps"], seconds=seconds,
                   tracing=tracing, trace_from=traffic["trace_from_step"],
                   trace_steps=traffic["trace_steps"],
-                  trace_dir=str(ROOT / ".bench_trace" / cell.name),
+                  trace_dir=str(cell.root / ".bench_trace" / cell.name),
                   b1=config["train"]["b1"])
 
     class Pipeline(DataPipeline):
@@ -365,12 +376,54 @@ class TraceContext:
     sampler_cpu_s: Optional[float]
     sampler_window_s: float
     agent: bool
+    device_kind: str = ""
+    # busy seconds by HLO instruction, each busy nanosecond given to the
+    # innermost operation (``programtrace.innermost``), mean over chips
+    device_s_by_op: Dict[str, float] = dataclasses.field(
+        default_factory=dict)
+    op_path: Dict[str, str] = dataclasses.field(   # instruction -> op name
+        default_factory=dict)
+    # device idle seconds in the program's loop spans, by
+    # ``programtrace.IDLE_GROUPS`` group, for the groups whose spans the
+    # traced stretch holds; mean over chips
+    idle_s_by_span: Dict[str, float] = dataclasses.field(
+        default_factory=dict)
+
+    def in_scope(self, name: str) -> float:
+        """Busy seconds of the operations whose op name has ``name`` as a
+        component (``programtrace.components``), nested scopes included."""
+        return programtrace.in_scope(self.device_s_by_op, self.op_path, name)
+
+
+def compiled_text(step, args) -> str:
+    """The HLO text of this program's own compile of ``step``.  The
+    persistent compile cache keys a module without its debug information,
+    where the scopes live, so a cached executable may carry the op names
+    of another build of the same program: compile past the cache."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return step.lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def _mean_by_key(dicts: List[Dict[str, float]]) -> Dict[str, float]:
+    """Each key's mean over ``dicts`` (0 where one lacks it); {} for none."""
+    keys = sorted({k for d in dicts for k in d})
+    return {k: sum(d.get(k, 0.0) for d in dicts) / len(dicts) for k in keys}
 
 
 def read_trace(cell: Cell, probe: Probe, kind: str, chips: int):
     """(TraceContext, breakdown) of the traced stretch of the window."""
     first, last = probe.trace_requests
     devices, spans = tracereduce.read_xplane(probe.trace_dir, SPANS)
+    loop_spans = programtrace.read_spans(probe.trace_dir)
     shutil.rmtree(probe.trace_dir, ignore_errors=True)
     starts = sorted(s for s, _, n in spans if n == "next_batch")
     lo, hi = starts[0], starts[-1]
@@ -387,6 +440,20 @@ def read_trace(cell: Cell, probe: Probe, kind: str, chips: int):
         "idle_gaps": [[n, t / 1e9] for n, t in
                       tracereduce.name_gaps(idle, spans)[:10]],
     }
+    by_op = _mean_by_key([programtrace.innermost(
+        ((s, e, tracereduce.op_name(n)) for s, e, n in devices[d]), lo, hi)
+        for d in used])
+    paths = (programtrace.op_paths(compiled_text(probe.step_fn,
+                                                 probe.step_args))
+             if by_op else {})
+    idle_by_span = _mean_by_key([programtrace.idle_by_span(
+        tracereduce.gaps(((s, e) for s, e, _ in devices[d]), lo, hi),
+        loop_spans, lo, hi) for d in used])
+    # a group none of whose spans the stretch holds has nothing to read
+    for group, names in programtrace.IDLE_GROUPS.items():
+        if not any(sp[2] in names and sp[1] > lo and sp[0] < hi
+                   for sp in loop_spans):
+            idle_by_span.pop(group, None)
     timings: Dict[str, List[float]] = {n: [] for n in SPANS}
     for req, name, secs in probe.timings:
         if first <= req < last:
@@ -397,12 +464,15 @@ def read_trace(cell: Cell, probe: Probe, kind: str, chips: int):
     ctx = TraceContext(
         cell=cell, steps=steps, window_s=(hi - lo) / 1e9,
         busy_s=statistics.mean(busy) / 1e9 if used else None,
-        step_flops=flops.train_step_flops(cell.config["model"],
-                                          cell.config["batch"],
-                                          cell.config["seq_len"]),
+        step_flops=flops.train_step_flops(
+            cell.config["model"], cell.config["batch"],
+            cell.config["seq_len"], flops.family(cell.config, cell.root)),
         peak_flops=peaks.peak(kind), timings=timings, sampler_cpu_s=cpu,
         sampler_window_s=probe.t_close - probe.t_open,
-        agent=probe.agent is not None)
+        agent=probe.agent is not None, device_kind=kind,
+        device_s_by_op={k: v / 1e9 for k, v in by_op.items()},
+        op_path=paths,
+        idle_s_by_span={k: v / 1e9 for k, v in idle_by_span.items()})
     return ctx, breakdown
 
 
@@ -420,7 +490,7 @@ def reference_readings(cell: Cell, seed: int, batches, q=reftrain.identity,
                        keep_rows: int = 0) -> reftrain.Readings:
     import jax
     config = cell.config
-    ref = reftrain.load_reference(config["reference"])
+    ref = reftrain.load_reference(config["reference"], cell.root)
     opt = dict(config["train"], peak_lr=cell.traffic["peak_lr"],
                warmup_steps=max(cell.traffic["total_steps"] // 20, 5))
     return reftrain.reference_steps(
@@ -468,6 +538,9 @@ def run(cell: Cell, seed: int, seconds: float, tracing: bool,
     if not cell.config.get("limits"):
         raise SystemExit(f"[bench] {cell.config_name}'s file states no "
                          f"limits, so no run of it can be judged correct")
+    # found before any run, so that a configuration without its FLOP
+    # count fails at once
+    flops.family(cell.config, cell.root)
     import jax
     from repro.launch.compile_cache import use_compile_cache
 
@@ -493,7 +566,7 @@ def run(cell: Cell, seed: int, seconds: float, tracing: bool,
         device.update(busy_s=ctx.busy_s, window_s=ctx.window_s)
         metrics = {}
         for m in cell.per_layer:
-            value = metric_reader(m["name"])(ctx)
+            value = metric_reader(m["name"], cell.root)(ctx)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
         result["breakdown"] = breakdown

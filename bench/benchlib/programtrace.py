@@ -4,10 +4,12 @@ and device idle by the program's host spans.
 Times are nanoseconds on the trace's clock, as in ``tracereduce``.
 
 - The model code puts ``jax.named_scope`` names on its operations
-  (``SCOPES``); each device operation carries the op name of its HLO
+  (``SCOPES``, and any other name, such as ``flash`` inside
+  ``attention``); each device operation carries the op name of its HLO
   instruction, a path such as ``jit(train_step)/transpose(jvp(attention))/
   dot_general``.  A scope counts an operation when it is a component of
-  that path, looked at through transform wrappers.
+  that path, looked at through transform wrappers, so nested scopes count
+  for each of their enclosing ones.
 - Each busy nanosecond of a device goes to the innermost operation running
   then: a loop's ``while`` covers the operations of its body and keeps
   only the time none of them covers.  So the scopes and the unscoped rest
@@ -43,26 +45,45 @@ PROGRAM_SPAN_PREFIX = "sysom."
 _WRAPPER = re.compile(r"^[\w\-]+\((.*)\)$")
 _OP_NAME = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?'
                       r'metadata=\{op_name="([^"]*)"', re.M)
+# a Pallas kernel's metadata, a JSON object of strings: printed over
+# several lines, closing with a brace at the start of a line, unless it is
+# empty and printed as ``{}``
+_KERNEL_METADATA = re.compile(r"kernel_metadata=\{\n.*?\n\}", re.S)
 
 
-def scope_of(op_path: str) -> str:
-    """The scope among ``SCOPES`` that is a component of ``op_path``
-    (``transpose(jvp(attention))`` counts as ``attention``), or
-    ``UNSCOPED``.  Merged metadata (``a;b``) is read by its first path."""
+def components(op_path: str) -> List[str]:
+    """The names along ``op_path``, each looked at through transform
+    wrappers (``transpose(jvp(attention))`` is ``attention``).  Merged
+    metadata (``a;b``) is read by its first path."""
+    out = []
     for comp in op_path.split(";", 1)[0].split("/"):
         m = _WRAPPER.match(comp)
         while m:
             comp = m.group(1)
             m = _WRAPPER.match(comp)
-        if comp in SCOPES:
-            return comp
-    return UNSCOPED
+        out.append(comp)
+    return out
+
+
+def scope_of(op_path: str) -> str:
+    """The first scope among ``SCOPES`` that is a component of
+    ``op_path``, or ``UNSCOPED``."""
+    return next((c for c in components(op_path) if c in SCOPES), UNSCOPED)
 
 
 def op_paths(hlo_text: str) -> Dict[str, str]:
     """Instruction name -> its ``metadata={op_name=...}``, from the text of
     a compiled HLO module."""
-    return dict(_OP_NAME.findall(hlo_text))
+    return dict(_OP_NAME.findall(
+        _KERNEL_METADATA.sub("kernel_metadata={}", hlo_text)))
+
+
+def in_scope(by_op: Mapping[str, float], paths: Mapping[str, str],
+             name: str) -> float:
+    """Busy time of the instructions whose op path has ``name`` as a
+    component, from busy time by instruction name."""
+    return sum(t for op, t in by_op.items()
+               if name in components(paths.get(op, "")))
 
 
 def innermost(events: Iterable[Tuple[float, float, str]], lo: float,

@@ -2,9 +2,11 @@
 
 ``reference_steps`` runs a reference module (``bench/reference/<name>.py``)
 through the first steps of training as a configuration states them: mean
-next-token cross-entropy over the whole batch, its gradient clipped to a
-global norm, AdamW with decoupled weight decay on every stored leaf of two
-or more dimensions, the learning rate warmed up linearly, and the
+next-token cross-entropy over the whole batch, plus the mean over rows of
+the reference's ``extra_loss`` where it defines one (such as a router's
+sequence-wise auxiliary loss), its gradient clipped to a global norm,
+AdamW with decoupled weight decay on every stored leaf of two or more
+dimensions, the learning rate warmed up linearly, and the
 parameters stored in the configuration's type after each update.  The
 arithmetic is float32 at the highest matmul precision; the control passes
 a ``q`` that rounds every matmul operand to a lower precision.
@@ -13,8 +15,14 @@ The batch goes through a row at a time, so the reference fits on one chip
 beside nothing else: gradients are summed over the rows and divided by the
 batch's token count.
 
+A reference module defines ``init_params(key, m)``, ``hidden(params,
+tokens, m, q)`` and ``head_matrix(params, m)``, and may define
+``extra_loss(params, tokens, m, q)``: the term of each row of ``tokens``
+that the program adds to the cross-entropy it differentiates.
+
 What it returns, and what the run reads from the program, is a
-``Readings``: the loss of each step, the norm of each leaf of the first
+``Readings``: the loss of each step (the cross-entropy alone, as the
+program reports it), the norm of each leaf of the first
 step's gradient as the optimizer gets it, and the norm of each leaf's
 change over the steps.  ``gaps`` compares two of them.
 """
@@ -22,7 +30,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import importlib
 import math
 import statistics
 import sys
@@ -33,13 +40,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-REFERENCE_DIR = Path(__file__).resolve().parents[1] / "reference"
+from benchlib.spec import ROOT, load_module
 
 
-def load_reference(name: str):
-    if str(REFERENCE_DIR) not in sys.path:
-        sys.path.insert(0, str(REFERENCE_DIR))
-    return importlib.import_module(name)
+def load_reference(name: str, root: Path = ROOT):
+    """The module ``bench/reference/<name>.py`` under ``root``; the
+    modules beside it (``lm_common``) are importable by name."""
+    ref_dir = Path(root) / "bench" / "reference"
+    if str(ref_dir) not in sys.path:
+        sys.path.insert(0, str(ref_dir))
+    return load_module(ref_dir / f"{name}.py")
 
 
 def leaf_paths(tree) -> List[str]:
@@ -107,20 +117,30 @@ fp8.defvjp(lambda x: (fp8_round(x), None), lambda _, g: (fp8_round(g),))
 def _programs(ref, m, opt, q):
     """The reference's jitted block gradient and update, built once per
     (reference, sizes, optimizer, precision)."""
-    key = (ref.__name__, _Frozen(m), _Frozen(opt), q)
+    key = (ref, _Frozen(m), _Frozen(opt), q)
     if key in _PROGRAMS:
         return _PROGRAMS[key]
     store = jnp.dtype(m["param_dtype"])
+    extra_loss = getattr(ref, "extra_loss", None)
 
     def block_loss(params, tokens, labels):
+        """(what is differentiated, the cross-entropy), summed over the
+        block's tokens."""
         h = ref.hidden(params, tokens, m, q)
         logits = q(q(h) @ q(ref.head_matrix(params, m)))
-        return ce_sum(logits, labels, m["vocab_size"])
+        ce = ce_sum(logits, labels, m["vocab_size"])
+        if extra_loss is None:
+            return ce, ce
+        # a row's term counted once per token: over the batch's token
+        # count, the sum over rows is the mean over rows
+        extra = jnp.sum(extra_loss(params, tokens, m, q)) * tokens.shape[1]
+        return ce + extra, ce
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def accumulate(params, acc, tokens, labels):
         p32 = jax.tree.map(lambda x: x.astype(jnp.float32), params)
-        loss, g = jax.value_and_grad(block_loss)(p32, tokens, labels)
+        (_, loss), g = jax.value_and_grad(block_loss, has_aux=True)(
+            p32, tokens, labels)
         return jax.tree.map(jnp.add, acc, g), loss
 
     @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))

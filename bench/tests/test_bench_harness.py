@@ -146,23 +146,88 @@ def test_a_sound_run_is_correct_and_its_last_line_has_the_result_keys(
         assert set(check) == {"value", "limit"}
 
 
+# A configuration added as files alone: its family's count of FLOPs in the
+# cell's own root (here in place of the dense count copied there); a
+# reference with an extra loss term (zero, as the dense program adds none);
+# and metrics that read the step's FLOPs and a scope's device time.
+PLANTED = {
+    "flops/dense.py": (
+        "def layer_flops(m, seq):\n"
+        "    return 1000 * m['d_model'] + seq\n"),
+    "reference/dense_lm_extra.py": (
+        "import jax.numpy as jnp\n"
+        "from dense_lm import head_matrix, hidden, init_params  # noqa\n\n\n"
+        "def extra_loss(params, tokens, m, q):\n"
+        "    return 0.0 * jnp.sum(params['final_norm']) + jnp.zeros(\n"
+        "        tokens.shape[0])\n"),
+    "metrics/planted_step_flops.py": (
+        "def read(ctx):\n"
+        "    return ctx.step_flops\n"),
+    "metrics/planted_attention_ms.py": (
+        "def read(ctx):\n"
+        "    busy = ctx.in_scope('attention')\n"
+        "    return 1e3 * busy / ctx.steps if busy > 0 else None\n"),
+}
+
+
+def _plant(root, name):
+    for rel, text in PLANTED.items():
+        (root / "bench" / rel).write_text(text)
+    config_path = root / "bench/configs/tiny.json"
+    config = json.loads(config_path.read_text())
+    config.update(reference="dense_lm_extra")
+    config_path.write_text(json.dumps(config))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    for metric, unit in (("planted_step_flops", "FLOP"),
+                         ("planted_attention_ms", "ms")):
+        bench["per_layer"].append({
+            "name": metric, "unit": unit, "better": "lower",
+            "source": "device_trace", "layer": "model step",
+            "moves": "tokens_per_s", "workloads": [name]})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return config
+
+
+@pytest.mark.parametrize("cpu_ops", [False, True],
+                         ids=["no_device_plane", "cpu_ops_as_device"])
 def test_a_traced_run_reads_the_host_spans(monkeypatch, tmp_path,
-                                           jax_config):
+                                           jax_config, cpu_ops):
     name = tinycell.make_root(tmp_path, "qwen2-0.5b")
+    config = _plant(tmp_path, name)
     tinycell.use_cpu(monkeypatch, tmp_path, "qwen2-0.5b")
+    if cpu_ops:
+        tinycell.cpu_ops_as_device(monkeypatch)
     cell = spec.find_cell(name, tmp_path)
     cell.traffic.update(trace_from_step=2, trace_steps=12)
-    monkeypatch.setattr(harness, "ROOT", tmp_path)
     # a stand-in peak, so that the reduction runs; never a device number
-    monkeypatch.setitem(peaks.PEAKS, "cpu", {"flops_bf16": 1e12})
+    monkeypatch.setitem(peaks.PEAKS, "cpu", {"flops_bf16": 1e12,
+                                             "hbm_bw": 1e11})
     res = harness.run(cell, 11, 3.0, True, 0.0)
     assert res["correct"] is True, res["checks"]
-    metrics = res["metrics"]
-    # the CPU has no device plane: device metrics are left out, not 0
-    assert "device_idle_share" not in metrics
+    metrics = {k: v["value"] for k, v in res["metrics"].items()}
     for name in ("input_wait_ms_per_step", "agent_ms_per_step",
                  "sampler_cpu_share", "step_mfu"):
-        assert metrics[name]["value"] >= 0, name
+        assert metrics[name] >= 0, name
+    m, b, s = config["model"], config["batch"], config["seq_len"]
+    assert metrics["planted_step_flops"] == 3.0 * b * s * (
+        m["num_layers"] * (1000 * m["d_model"] + s)
+        + 2 * m["d_model"] * m["vocab_size"])
+    # no operation runs under ``flash`` off the TPU
+    assert "flash_attention_roofline" not in metrics
+    if cpu_ops:
+        scopes = [metrics[f"{n}_ms_per_step"] for n in
+                  ("attention", "mlp", "head_loss", "optimizer")]
+        assert min(scopes) > 0
+        assert sum(scopes) < metrics["device_ms_per_step"]
+        assert metrics["planted_attention_ms"] == metrics[
+            "attention_ms_per_step"]
+        for name in ("dispatch", "step_wait", "loss_fetch", "observe"):
+            assert metrics[f"{name}_idle_ms_per_step"] >= 0, name
+    else:
+        # the CPU has no device plane: device metrics are left out, not 0
+        for name in ("device_idle_share", "planted_attention_ms",
+                     "attention_ms_per_step", "dispatch_idle_ms_per_step"):
+            assert name not in metrics, name
     assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
     assert not (tmp_path / ".bench_trace").exists() or not any(
         (tmp_path / ".bench_trace").iterdir())
@@ -244,3 +309,66 @@ def test_the_control_is_not_correct(monkeypatch, tmp_path, jax_config, arch):
     gaps = reftrain.gaps(ctl, ref)
     limits = cell.config["limits"]
     assert any(gaps[k] > limits[k] for k in limits), gaps
+
+
+def _tiny_batches(cell, seed):
+    from repro.data import SyntheticCorpus
+    corpus = SyntheticCorpus(cell.config["model"]["vocab_size"],
+                             seq_len=cell.config["seq_len"], seed=seed)
+    batches = []
+    for step in range(harness.CAPTURE_STEPS):
+        seqs = np.stack([corpus.sequence(step * 4 + i) for i in range(4)])
+        batches.append({"tokens": seqs[:, :-1], "labels": seqs[:, 1:]})
+    return batches
+
+
+def _with_extra_loss(ref, name, extra_loss):
+    import types
+    mod = types.ModuleType(name)
+    for fn in ("init_params", "hidden", "head_matrix"):
+        setattr(mod, fn, getattr(ref, fn))
+    mod.extra_loss = extra_loss
+    return mod
+
+
+def test_a_references_extra_loss_moves_the_gradient_not_the_loss(tmp_path):
+    import jax.numpy as jnp
+    name = tinycell.make_root(tmp_path, "qwen2-0.5b")
+    cell = spec.find_cell(name, tmp_path)
+    config = cell.config
+    ref = reftrain.load_reference(config["reference"], tmp_path)
+    opt = dict(config["train"], peak_lr=cell.traffic["peak_lr"],
+               warmup_steps=max(cell.traffic["total_steps"] // 20, 5))
+    batches = _tiny_batches(cell, 5)
+
+    def readings(module):
+        return reftrain.reference_steps(module, config["model"], opt,
+                                        batches, jax.random.PRNGKey(5))
+
+    plain = readings(ref)
+    zero = readings(_with_extra_loss(
+        ref, "zero_extra", lambda p, t, m, q: jnp.zeros(t.shape[0])))
+    assert zero == plain
+    # a term on the final norm's weight moves its gradient and the norms
+    # compared, and leaves the compared loss, the cross-entropy, alone
+    pull = _with_extra_loss(ref, "norm_pull", lambda p, t, m, q: jnp.sum(
+        p["final_norm"]) * jnp.ones(t.shape[0]))
+    moved = readings(pull)
+    assert moved.losses[0] == plain.losses[0]
+    assert reftrain.gaps(moved, plain)["grad_norm_gap"] > 0.1
+    # summed over the rows and divided by the token count, the term's
+    # gradient is that of its mean over rows: 1 on each weight
+    tokens, labels = batches[0]["tokens"], batches[0]["labels"]
+    grads = []
+    for module in (ref, pull):
+        init, accumulate, _ = reftrain._programs(
+            module, config["model"], opt, reftrain.identity)
+        params = init(jax.random.PRNGKey(5), reftrain._Frozen(
+            config["model"]))
+        acc = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32),
+                           params)
+        for r in range(tokens.shape[0]):
+            acc, _ = accumulate(params, acc, tokens[r:r + 1],
+                                labels[r:r + 1])
+        grads.append(np.asarray(acc["final_norm"]) / tokens.size)
+    np.testing.assert_allclose(grads[1] - grads[0], 1.0, rtol=1e-5)

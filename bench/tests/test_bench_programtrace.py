@@ -167,7 +167,6 @@ def test_a_traced_cpu_run_finds_the_programs_spans(monkeypatch, tmp_path):
     tinycell.use_cpu(monkeypatch, tmp_path, "qwen2-0.5b")
     cell = spec.find_cell(name, tmp_path)
     cell.traffic.update(trace_from_step=2, trace_steps=12)
-    monkeypatch.setattr(harness, "ROOT", tmp_path)
     monkeypatch.setitem(peaks.PEAKS, "cpu", {"flops_bf16": 1e12})
     was = jax.config.jax_persistent_cache_min_compile_time_secs
     try:
@@ -206,7 +205,6 @@ def test_the_op_names_are_the_programs_own_past_the_compile_cache(
     a build that differs only in its scopes gets the other's op names."""
     import jax
     import jax.numpy as jnp
-    import trace_report
     from jax.experimental.compilation_cache import compilation_cache
 
     def build(scope):
@@ -230,10 +228,122 @@ def test_the_op_names_are_the_programs_own_past_the_compile_cache(
         assert "before/" in build("before").lower(x).compile().as_text()
         cached = build("after").lower(x).compile().as_text()
         assert "before/" in cached and "after/" not in cached
-        own = trace_report.compiled_text(build("after"), (x,))
+        own = harness.compiled_text(build("after"), (x,))
         assert "after/" in own and "before/" not in own
         assert jax.config.jax_enable_compilation_cache
     finally:
         for n, v in was.items():
             jax.config.update(n, v)
         compilation_cache.reset_cache()
+
+
+# Pallas kernels as the compiled HLO prints them: the splash kernels' block
+# sizes over several lines before the op name (a TPU v5e compile of the
+# train step); a kernel without metadata prints ``{}`` on one line, and the
+# computation closes after the fusion that follows it.
+KERNEL_HLO = """
+  %splash_mqa_fwd_residuals.9 = (bf16[2,7,1024,64]{3,2,1,0}, f32[2,7,1024,128]{3,2,1,0}) custom-call(%fusion.471, %fusion.474), custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={
+"xprof_metadata":"{\\"block_q\\": 512, \\"block_kv\\": 512, \\"q_layout\\": 2}"
+}}, metadata={op_name="jit(train_step)/jvp()/while/body/closed_call/attention/cond/branch_0_fun/flash/vmap(jit(_splash_attention))/splash_mqa_fwd_residuals/pallas_call" stack_frame_id=75}, backend_config={"flag_configs":[]}
+  %closed_call.31 = bf16[2,7,1024,64]{3,2,1,0} get-tuple-element(%splash_mqa_fwd_residuals.9), index=0, frontend_attributes={kernel_metadata={
+"xprof_metadata":"{\\"block_q\\": 512}"
+}}, metadata={op_name="jit(train_step)/jvp()/while/body/closed_call/attention/cond/branch_0_fun/flash/vmap(jit(_splash_attention))/splash_mqa_fwd_residuals/pallas_call" stack_frame_id=75}
+  %splash_mqa_dq_no_residuals.12 = bf16[2,7,1024,64]{3,2,1,0} custom-call(%fusion.1), custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={
+"xprof_metadata":"{\\"block_q_dq\\": 512}"
+}}, metadata={op_name="jit(train_step)/transpose(jvp())/while/body/closed_call/checkpoint/attention/cond/branch_0_fun/flash/vmap(jit(_splash_attention))/splash_mqa_dq_no_residuals/pallas_call" stack_frame_id=62}
+  %fusion.7 = f32[4]{0} fusion(%p), kind=kLoop, calls=%f7, metadata={op_name="jit(train_step)/transpose(jvp(attention))/mul"}
+  %fusion.11 = bf16[2,7,64,1024]{3,2,1,0} fusion(%p), kind=kLoop, calls=%f11, metadata={op_name="jit(train_step)/jvp()/while/body/closed_call/attention/cond/branch_0_fun/flash/transpose"}
+  %rms_norm.3 = bf16[4096,896]{1,0} custom-call(%fusion.2), custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={}}, metadata={op_name="jit(train_step)/jvp()/while/body/closed_call/mlp/rms_norm/pallas_call"}
+  ROOT %fusion.10 = f32[4]{0} fusion(%p), kind=kLoop, calls=%f10, metadata={op_name="jit(train_step)/jvp(mlp)/mul"}
+}
+"""
+
+
+def test_a_kernel_with_metadata_keeps_its_op_name():
+    paths = programtrace.op_paths(KERNEL_HLO)
+    assert set(paths) == {"splash_mqa_fwd_residuals.9", "closed_call.31",
+                          "splash_mqa_dq_no_residuals.12", "fusion.7",
+                          "fusion.11", "rms_norm.3", "fusion.10"}
+    assert paths["splash_mqa_dq_no_residuals.12"].endswith(
+        "/splash_mqa_dq_no_residuals/pallas_call")
+    assert programtrace.scope_of(paths["splash_mqa_fwd_residuals.9"]) == \
+        "attention"
+    # an empty ``{}`` hides neither its own op name nor the next one's
+    assert paths["rms_norm.3"].endswith("/mlp/rms_norm/pallas_call")
+    assert programtrace.scope_of(paths["fusion.10"]) == "mlp"
+
+
+def test_a_scope_counts_its_nested_scopes_and_any_name_is_read():
+    by_op = {"splash_mqa_fwd_residuals.9": 6.0,
+             "splash_mqa_dq_no_residuals.12": 7.5, "fusion.7": 2.0,
+             "fusion.8": 3.0, "fusion.9": 1.0, "copy.1": 0.5}
+    paths = dict(programtrace.op_paths(KERNEL_HLO),
+                 **{"fusion.8": "jit(train_step)/jvp(mlp)/experts/"
+                                "transpose(jvp(router))/dot_general",
+                    "fusion.9": "jit(train_step)/mlp/mul;"
+                                "jit(train_step)/experts/mul"})
+    assert programtrace.in_scope(by_op, paths, "attention") == 15.5
+    assert programtrace.in_scope(by_op, paths, "flash") == 13.5
+    # names the scopes of SCOPES never had, inside ``mlp``
+    assert programtrace.in_scope(by_op, paths, "experts") == 3.0
+    assert programtrace.in_scope(by_op, paths, "router") == 3.0
+    assert programtrace.in_scope(by_op, paths, "mlp") == 4.0
+    assert programtrace.in_scope(by_op, paths, "optimizer") == 0
+    # a component, not a substring: ``_splash_attention`` is no
+    # ``attention``, and ``flash`` no ``fla``
+    assert programtrace.in_scope(by_op, paths, "fla") == 0
+    # the nested names do not change the split by ``SCOPES``
+    scopes = programtrace.device_by_scope(by_op, paths)
+    assert scopes == {"attention": 15.5, "mlp": 4.0, "unscoped": 0.5}
+
+
+def _context(by_op, paths, idle, steps=2):
+    return harness.TraceContext(
+        cell=spec.find_cell("qwen2-0.5b.train.agent"), steps=steps,
+        window_s=1.0, busy_s=sum(by_op.values()), step_flops=1.0,
+        peak_flops=1.0, timings={}, sampler_cpu_s=None,
+        sampler_window_s=1.0, agent=True, device_kind="TPU v5 lite",
+        device_s_by_op=by_op, op_path=paths, idle_s_by_span=idle)
+
+
+def test_the_scope_metrics_and_the_rest_add_up_to_device_time():
+    by_op = programtrace.innermost(DEVICE, LO, HI)
+    paths = programtrace.op_paths(HLO)
+    ctx = _context(by_op, paths, {"dispatch": 20, "step_wait": 17})
+    read = {m: spec.metric_reader(m)(ctx) for m in (
+        "attention_ms_per_step", "mlp_ms_per_step", "head_loss_ms_per_step",
+        "optimizer_ms_per_step", "device_ms_per_step",
+        "flash_attention_roofline", "dispatch_idle_ms_per_step",
+        "step_wait_idle_ms_per_step", "observe_idle_ms_per_step")}
+    assert [read[f"{n}_ms_per_step"] for n in (
+        "attention", "mlp", "head_loss", "optimizer")] == [
+        1e3 * 25 / 2, 1e3 * 55 / 2, 1e3 * 45 / 2, 1e3 * 20 / 2]
+    rest = programtrace.device_by_scope(by_op, paths)
+    assert sum(read[f"{n}_ms_per_step"] for n in (
+        "attention", "mlp", "head_loss", "optimizer")) + 1e3 * (
+        rest.get("embed", 0) + rest["unscoped"]) / 2 == \
+        read["device_ms_per_step"]
+    # nothing ran under ``flash``, and no ``observe`` span was held
+    assert read["flash_attention_roofline"] is None
+    assert read["observe_idle_ms_per_step"] is None
+    assert read["dispatch_idle_ms_per_step"] == 1e3 * 20 / 2
+    assert read["step_wait_idle_ms_per_step"] == 1e3 * 17 / 2
+
+
+def test_the_attention_kernels_roofline_is_their_least_time_over_theirs():
+    # qwen2-0.5b's step: 541 GFLOP of attention at 197 TFLOP/s is
+    # 2.747 ms, over the 0.99 ms its 811 MB take at 819 GB/s
+    kernels = spec.metric_module("flash_attention_roofline")
+    paths = programtrace.op_paths(KERNEL_HLO)
+    # the kernels count; not the layout under ``flash`` (fusion.11), the
+    # rest of ``attention`` (fusion.7) or a kernel elsewhere (rms_norm.3)
+    by_op = {"splash_mqa_fwd_residuals.9": 30 * 6.25e-3,
+             "splash_mqa_dq_no_residuals.12": 30 * 7.71e-3,
+             "fusion.7": 30 * 10e-3, "fusion.11": 30 * 1.8e-3,
+             "rms_norm.3": 30 * 1e-3}
+    ctx = _context(by_op, paths, {}, steps=30)
+    assert kernels.kernel_s(ctx) == pytest.approx(30 * (6.25e-3 + 7.71e-3))
+    least = kernels.flops(ctx.cell.config["model"], 4, 1024) / 197e12
+    assert kernels.read(ctx) == pytest.approx(
+        100 * least / (6.25e-3 + 7.71e-3))
+    assert 19 < kernels.read(ctx) < 20

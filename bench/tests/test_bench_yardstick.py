@@ -56,14 +56,24 @@ def _matmul_params(cfg) -> float:
     return n
 
 
+# each configuration's step FLOPs as counted before the count of a layer
+# moved to its family's file: the move changes no bit
+STEP_FLOPS = {"qwen2-0.5b": 13221922603008.0,
+              "mamba2-370m": 20642820784128.0}
+
+
 @pytest.mark.parametrize("config", ["qwen2-0.5b", "mamba2-370m"])
 def test_flops_match_the_parameter_count(config):
     from repro import configs
+    name = config
     config = json.loads((BENCH / "configs" / f"{config}.json").read_text())
     m = config["model"]
     cfg = configs.get(config["registry"])
     seq = config["seq_len"]
-    per_token = flops.forward_flops_per_token(m, seq)
+    layer_flops = flops.family(config)
+    per_token = flops.forward_flops_per_token(m, seq, layer_flops)
+    assert flops.train_step_flops(m, config["batch"], seq, layer_flops) \
+        == STEP_FLOPS[name]
     if m["family"] == "dense":
         mixing = m["num_layers"] * 4 * seq * m["num_heads"] * (
             m["d_model"] // m["num_heads"])
@@ -77,7 +87,40 @@ def test_flops_match_the_parameter_count(config):
     # real vocabulary, the parameter count the padded one)
     assert (per_token - mixing) / (2 * _matmul_params(cfg)) == \
         pytest.approx(1.0, rel=2e-3)
-    assert flops.train_step_flops(m, 4, seq) == 3 * 4 * seq * per_token
+    assert flops.train_step_flops(m, 4, seq, layer_flops) == \
+        3 * 4 * seq * per_token
+
+
+def test_a_family_file_is_found_under_the_cells_root(tmp_path):
+    tinycell.make_root(tmp_path, "qwen2-0.5b")
+    # the shipped count, copied into the root, is found by the family
+    config = {"model": {"family": "dense"}}
+    assert flops.family(config, tmp_path)({"d_model": 4, "d_ff": 2,
+                                           "num_heads": 1,
+                                           "num_kv_heads": 1,
+                                           "head_dim": 0}, 8) == (
+        2 * 4 * 12 + 2 * 4 * 4 + 4 * 8 * 4 + 6 * 4 * 2)
+    # a new family fails and names the file to add, until it is added
+    config["model"]["family"] = "moe"
+    with pytest.raises(FileNotFoundError, match="bench/flops/moe.py"):
+        flops.family(config, tmp_path)
+    (tmp_path / "bench/flops/moe.py").write_text(
+        "def layer_flops(m, seq):\n    return 2 * seq\n")
+    assert flops.family(config, tmp_path)({}, 8) == 16
+
+
+def test_the_attention_kernels_work_is_the_hand_count():
+    from benchlib import spec
+    kernels = spec.metric_module("flash_attention_roofline")
+    config = json.loads((BENCH / "configs" / "qwen2-0.5b.json").read_text())
+    m, b, s = config["model"], config["batch"], config["seq_len"]
+    # 3 x 2 S^2 h x B x heads x layers: six products at the causal half
+    assert kernels.flops(m, b, s) == 3 * 2 * s * s * 64 * b * 14 * 24 \
+        == 541165879296
+    # q, o, dO, dq over 14 heads and k, v, dk, dv over 2, in bf16; the
+    # log-sum-exp of the 14 heads in f32; in each of 24 layers
+    assert kernels.bytes_moved(m, b, s) == 24 * b * s * (
+        2 * (4 * 14 * 64 + 4 * 2 * 64) + 4 * 14) == 810811392
 
 
 def test_peaks_are_keyed_by_device_kind_and_refuse_unknown_kinds():

@@ -1,9 +1,11 @@
 """A benchmark cell at a tiny size, for the benchmark's own tests on the CPU.
 
 ``make_root`` writes a checkout-like directory holding ``BENCHMARK.json``
-and the data files of one cell whose configuration is the registry's tiny
-preset of an architecture; ``use_cpu`` steers the harness onto the CPU.
-The steering lives here, in the tests, and not in options of the harness.
+and the files of one cell whose configuration is the registry's tiny
+preset of an architecture; ``use_cpu`` steers the harness onto the CPU,
+and ``cpu_ops_as_device`` reads the CPU's XLA operations in a trace as
+the operations of a device.  The steering lives here, in the tests, and
+not in options of the harness.
 """
 from __future__ import annotations
 
@@ -54,7 +56,9 @@ def make_root(tmp: Path, arch: str, traffic: str = "agent",
                                              else 32)
     config["limits"] = TINY_LIMITS[arch]
     (tmp / "bench" / "configs").mkdir(parents=True)
-    shutil.copytree(BENCH / "traffic", tmp / "bench" / "traffic")
+    for part in ("traffic", "metrics", "flops", "reference"):
+        shutil.copytree(BENCH / part, tmp / "bench" / part,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     (tmp / "bench" / "configs" / "tiny.json").write_text(json.dumps(config))
     name = f"tiny.{traffic}"
     bench["configs"] = [{"name": "tiny", "source": real["source"],
@@ -85,3 +89,29 @@ def use_cpu(monkeypatch, tmp: Path, arch: str, param_dtype="bfloat16"):
     # set, so the program leaves the cache to JAX, which read it (unset)
     # when it was imported: nothing is written
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp / "cache"))
+
+
+def cpu_ops_as_device(monkeypatch):
+    """Trace readings in which the operations that XLA's CPU threads ran
+    stand as the operations of one device, ``/device:TPU:0``; the host
+    plane is read as before."""
+    from jax.profiler import ProfileData
+
+    from benchlib import tracereduce
+    real = tracereduce.read_xplane
+
+    def read_xplane(trace_dir, span_names):
+        devices, spans = real(trace_dir, span_names)
+        path, = Path(trace_dir).glob("**/*.xplane.pb")
+        devices["/device:TPU:0"] = sorted(
+            (e.start_ns, e.start_ns + e.duration_ns, e.name)
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines
+            if line.name.startswith("tf_XLA")
+            for e in line.events
+            if not e.name.startswith(("ThreadpoolListener", "ThunkExecutor",
+                                      "end: ")))
+        return devices, spans
+
+    monkeypatch.setattr(tracereduce, "read_xplane", read_xplane)

@@ -3,15 +3,15 @@
     python3 bench/trace_report.py --workload <name> --seed <n> --seconds <s>
 
 from the root of a checkout, on a machine with the TPU chips the cell asks
-for.  It drives the cell as a ``bench/run.py --trace 1`` run does, and
-before the harness reads the trace it reads it by the names the program
-puts there (``benchlib.programtrace``): device time per traced step by
-model scope, with the op names taken from the train step's HLO, compiled
-anew after the window; device idle per traced step by the loop's
-``sysom.loop.*`` spans; and how far each step's last device operation ran
-past its ``sysom.loop.step_wait``.  Beside them stand the harness's own
-per-layer metrics and breakdown, and the host cost of the spans the loop
-opens in a step while no trace is taken.  The last line of standard output
+for.  It drives the cell as a ``bench/run.py --trace 1`` run does and
+reads the trace by the names the program puts there
+(``benchlib.programtrace``): device time per traced step by model scope,
+with the op names taken from the train step's HLO, compiled anew after
+the window (``harness.read_trace``); device idle per traced step by the
+loop's ``sysom.loop.*`` spans; and how far each step's last device
+operation ran past its ``sysom.loop.step_wait``.  Beside them stand the
+harness's own per-layer metrics and breakdown, and the host cost of the
+spans the loop opens in a step while no trace is taken.  The last line of standard output
 is one JSON object.  The correctness check of ``bench/run.py`` is left out.
 """
 import time
@@ -79,24 +79,6 @@ def host_cost_us_per_step(steps: int = HOST_COST_STEPS):
     return 1e6 * (best(annotated) - best(bare)) / steps
 
 
-def compiled_text(step, args) -> str:
-    """The HLO text of this program's own compile of ``step``.  The
-    persistent compile cache keys a module without its debug information,
-    where the scopes live, so a cached executable may carry the op names
-    of another build of the same program: compile past the cache."""
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache
-
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        return step.lower(*args).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
-
-
 def report(cell, seed: int, seconds: float, t_start: float) -> dict:
     """Drive one traced run of ``cell`` and read it (see the module)."""
     import jax
@@ -105,29 +87,7 @@ def report(cell, seed: int, seconds: float, t_start: float) -> dict:
     devs = harness.check_devices(cell.chips)
     use_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-
-    # the loop's jitted train step and its arguments' shapes, for its HLO
-    seen = {}
-    observe = harness.Probe.observe_step
-
-    def observe_step(probe, jitted):
-        step = observe(probe, jitted)
-
-        def first(state, batch):
-            if not seen:
-                seen["step"] = jitted
-                seen["args"] = jax.tree.map(
-                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                                   sharding=x.sharding),
-                    (state, batch))
-            return step(state, batch)
-        return first
-
-    harness.Probe.observe_step = observe_step
-    try:
-        probe = harness.drive(cell, seed, seconds, True)
-    finally:
-        harness.Probe.observe_step = observe
+    probe = harness.drive(cell, seed, seconds, True)
     e2e = harness.end_to_end(cell, probe, t_start)
 
     devices, marks = tracereduce.read_xplane(probe.trace_dir, ("next_batch",))
@@ -136,7 +96,6 @@ def report(cell, seed: int, seconds: float, t_start: float) -> dict:
                                         cell.chips)
     starts = sorted(s for s, _, _ in marks)
     lo, hi, steps = starts[0], starts[-1], len(starts) - 1
-    paths = programtrace.op_paths(compiled_text(seen["step"], seen["args"]))
 
     lengths = {}
     for s, e, name, _ in spans:
@@ -146,7 +105,7 @@ def report(cell, seed: int, seconds: float, t_start: float) -> dict:
            "step_period_ms_median": statistics.median(
                b - a for a, b in zip(starts, starts[1:])) / 1e6,
            "end_to_end": e2e,
-           "per_layer": {m["name"]: metric_reader(m["name"])(ctx)
+           "per_layer": {m["name"]: metric_reader(m["name"], cell.root)(ctx)
                          for m in cell.per_layer},
            "breakdown": breakdown,
            "host_cost_us_per_step": host_cost_us_per_step(),
@@ -155,31 +114,28 @@ def report(cell, seed: int, seconds: float, t_start: float) -> dict:
                             for n, v in lengths.items()}}
     if not devices:
         return out
-    ops = [(s, e, tracereduce.op_name(n))
-           for s, e, n in devices[sorted(devices)[0]]]
-    by_op = programtrace.innermost(ops, lo, hi)
-    scopes = programtrace.device_by_scope(by_op, paths)
-    unscoped = sorted(((t, n) for n, t in by_op.items() if
-                       programtrace.scope_of(paths.get(n, "")) ==
+    ops = [(s, e) for s, e, _ in devices[sorted(devices)[0]]]
+    per_step = {k: v * 1e3 / steps for k, v in ctx.device_s_by_op.items()}
+    scopes = programtrace.device_by_scope(per_step, ctx.op_path)
+    unscoped = sorted(((t, n) for n, t in per_step.items() if
+                       programtrace.scope_of(ctx.op_path.get(n, "")) ==
                        programtrace.UNSCOPED), reverse=True)[:8]
-    idle = tracereduce.gaps(((s, e) for s, e, _ in ops), lo, hi)
+    idle = tracereduce.gaps(ops, lo, hi)
     # per step, between successive batch requests: a stall moves the mean
     # and not the median
     step_idle = [programtrace.overlap(idle, [(a, b)])
                  for a, b in zip(starts, starts[1:])]
-    by_span = programtrace.idle_by_span(idle, spans, lo, hi)
     # > 0: the step's last device operation ended after the wait
-    lags = [lag / 1e3 for _, lag in programtrace.step_wait_lag(
-        ((s, e) for s, e, _ in ops), spans)]
+    lags = [lag / 1e3 for _, lag in programtrace.step_wait_lag(ops, spans)]
     out.update(
-        busy_ms=sum(by_op.values()) / 1e6 / steps,
-        device_ms_by_scope={k: v / 1e6 / steps
-                            for k, v in sorted(scopes.items())},
-        unscoped_top_ms={n: [t / 1e6 / steps, paths.get(n, "")[-120:]]
+        busy_ms=sum(per_step.values()),
+        device_ms_by_scope=dict(sorted(scopes.items())),
+        unscoped_top_ms={n: [t, ctx.op_path.get(n, "")[-120:]]
                          for t, n in unscoped},
         idle_ms=sum(e - s for s, e in idle) / 1e6 / steps,
         idle_ms_median=statistics.median(step_idle) / 1e6,
-        idle_ms_by_span={k: v / 1e6 / steps for k, v in by_span.items()},
+        idle_ms_by_span={k: v * 1e3 / steps
+                         for k, v in ctx.idle_s_by_span.items()},
         step_wait_lag_us={"steps": len(lags),
                           "late": sum(lag > 0 for lag in lags),
                           "max": max(lags, default=None),
