@@ -1,4 +1,5 @@
-"""Architecture registry: 10 assigned archs + the paper's §5.1 eval model.
+"""Architecture registry: 10 assigned archs, the paper's §5.1 eval model
+and the benchmark's Moonlight-16B-A3B (published and one chip's share).
 
 Every module exposes ``CONFIG`` (the exact published config) and ``tiny()``
 (a reduced same-family config for CPU smoke tests).
@@ -22,9 +23,13 @@ _ARCH_MODULES = {
     "mamba2-370m": "mamba2_370m",
     "whisper-base": "whisper_base",
     "llama3.2-1b": "llama3_2_1b",  # paper's overhead-eval model (§5.1)
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
+    "moonlight-16b-a3b-ep8": "moonlight_16b_a3b_ep8",
 }
 
-ASSIGNED_ARCHS: List[str] = [a for a in _ARCH_MODULES if a != "llama3.2-1b"]
+_NOT_ASSIGNED = ("llama3.2-1b", "moonlight-16b-a3b", "moonlight-16b-a3b-ep8")
+ASSIGNED_ARCHS: List[str] = [a for a in _ARCH_MODULES
+                             if a not in _NOT_ASSIGNED]
 
 # long_500k needs sub-quadratic attention: runs for SSM/hybrid and SWA archs.
 _LONG_CONTEXT_OK = {"zamba2-2.7b", "mamba2-370m", "mixtral-8x22b"}

@@ -56,16 +56,19 @@ def _kernel(seq: int, group: int, interpret: bool):
 
 
 def causal_attention(q, k, v, *, interpret: bool = False):
-    """softmax(q k^T / sqrt(d), causal) v.  q (B, S, Hq, D), k and v
-    (B, S, Hkv, D), Hq a multiple of Hkv -> (B, S, Hq, D)."""
+    """softmax(q k^T / sqrt(D), causal) v.  q (B, S, Hq, D), k (B, S, Hkv,
+    D) and v (B, S, Hkv, Dv), Hq a multiple of Hkv -> (B, S, Hq, Dv).  The
+    value head may be narrower than the query/key head (latent attention's
+    192 and 128)."""
     b, s, nq, d = q.shape
-    nkv = k.shape[2]
+    nkv, dv = k.shape[2], v.shape[-1]
     g = nq // nkv
     qt = q.reshape(b, s, nkv, g, d).transpose(0, 2, 3, 1, 4).reshape(
         b * nkv, g, s, d)
     qt = (qt.astype(jnp.float32) * d ** -0.5).astype(q.dtype)
-    kt, vt = (x.transpose(0, 2, 1, 3).reshape(b * nkv, s, d) for x in (k, v))
+    kt, vt = (x.transpose(0, 2, 1, 3).reshape(b * nkv, s, x.shape[-1])
+              for x in (k, v))
     qt, kt, vt = (checkpoint_name(x, RESIDUALS) for x in (qt, kt, vt))
     out = jax.vmap(_kernel(s, g, interpret))(qt, kt, vt)
-    return out.reshape(b, nkv, g, s, d).transpose(0, 3, 1, 2, 4).reshape(
-        b, s, nq, d)
+    return out.reshape(b, nkv, g, s, dv).transpose(0, 3, 1, 2, 4).reshape(
+        b, s, nq, dv)

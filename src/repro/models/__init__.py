@@ -41,7 +41,7 @@ class Model:
 
 
 def build_model(cfg: ModelConfig) -> Model:
-    if cfg.family in ("dense", "moe", "vlm"):
+    if cfg.family in ("dense", "moe", "mla_moe", "vlm"):
         mod = transformer
     elif cfg.family in ("ssm", "hybrid"):
         mod = ssm_lm

@@ -1,4 +1,5 @@
-"""Multi-head attention: GQA/MQA, sliding windows, qk-norm, KV-cache decode.
+"""Multi-head attention: GQA/MQA, latent attention (MLA), sliding windows,
+qk-norm, KV-cache decode.
 
 ``attention_impl="ref"`` is exact softmax attention.  Full-sequence causal
 self-attention with no sliding window, at a length the kernels' blocks
@@ -8,6 +9,11 @@ in VMEM); every other call, and every other platform, materialises the
 scores with einsums, which are the fused kernels' oracle in the tests.
 ``cfg.use_pallas`` selects the forward-only Pallas flash kernel
 (``repro.kernels.flash_attention``) instead.
+
+Latent attention (``cfg.kv_lora_rank > 0``, DeepSeek-V2/V3 with no query
+LoRA) only changes how q, k and v are made: k and v come from one normed
+latent per token, and all heads share one rotary key.  Its query/key head
+(nope + rope) is wider than its value head; every path takes that.
 """
 from __future__ import annotations
 
@@ -29,6 +35,8 @@ NEG_INF = -1e30
 
 
 def init_attention(key, cfg: ModelConfig):
+    if cfg.is_mla:
+        return init_mla(key, cfg)
     d, h = cfg.d_model, cfg.resolved_head_dim
     nq, nkv = cfg.num_heads, cfg.num_kv_heads
     ks = jax.random.split(key, 4)
@@ -48,9 +56,28 @@ def init_attention(key, cfg: ModelConfig):
     return p
 
 
+def init_mla(key, cfg: ModelConfig):
+    d, nq, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, \
+        cfg.resolved_v_head_dim
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": layers.dense_init(ks[0], (d, nq, dn + dr), ("embed", "q_heads", "head_dim"), cfg, fan_in=d),
+        "wkv_a": layers.dense_init(ks[1], (d, r + dr), ("embed", "kv_lora"), cfg, fan_in=d),
+        "kv_norm": layers.zeros_init((r,), ("kv_lora",), cfg),
+        "wkv_b": layers.dense_init(ks[2], (r, nq, dn + dv), ("kv_lora", "q_heads", "head_dim"), cfg, fan_in=r),
+        "wo": layers.dense_init(ks[3], (nq, dv, d), ("q_heads", "head_dim", "embed"), cfg, fan_in=nq * dv),
+    }
+
+
 # ---------------------------------------------------------------------------
 # projections
 # ---------------------------------------------------------------------------
+
+
+def _rope(x, positions, cfg: ModelConfig):
+    return layers.apply_rope(x, positions, cfg.rope_theta, cfg.mrope_sections,
+                             interleaved=cfg.rope_type == "interleaved")
 
 
 def _project_qkv(params, x, cfg: ModelConfig, positions):
@@ -65,9 +92,34 @@ def _project_qkv(params, x, cfg: ModelConfig, positions):
         q = layers.rms_norm(q, params["q_norm"], cfg.norm_eps)
         k = layers.rms_norm(k, params["k_norm"], cfg.norm_eps)
     if cfg.rope_theta > 0:
-        q = layers.apply_rope(q, positions, cfg.rope_theta, cfg.mrope_sections)
-        k = layers.apply_rope(k, positions, cfg.rope_theta, cfg.mrope_sections)
+        q = _rope(q, positions, cfg)
+        k = _rope(k, positions, cfg)
     return q, k, v
+
+
+@jax.named_scope("mla")
+def _project_mla(params, x, cfg: ModelConfig, positions):
+    """q (B,S,H,nope+rope), k (B,S,H,nope+rope), v (B,S,H,v) from the
+    query projection and the normed latent; the rope part of k is one
+    head's, repeated."""
+    dn, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    q = jnp.einsum("bsd,dnh->bsnh", x, params["wq"])
+    q = jnp.concatenate([q[..., :dn], _rope(q[..., dn:], positions, cfg)],
+                        axis=-1)
+    kv_a = x @ params["wkv_a"]
+    latent = layers.rms_norm(kv_a[..., :r], params["kv_norm"], cfg.norm_eps)
+    k_rope = _rope(kv_a[..., None, r:], positions, cfg)      # (B,S,1,rope)
+    kv = jnp.einsum("bsr,rnh->bsnh", latent, params["wkv_b"])
+    k = jnp.concatenate(
+        [kv[..., :dn],
+         jnp.broadcast_to(k_rope, kv.shape[:3] + k_rope.shape[-1:])],
+        axis=-1)
+    return q, k, kv[..., dn:]
+
+
+def _project(params, x, cfg: ModelConfig, positions):
+    return (_project_mla if cfg.is_mla else _project_qkv)(params, x, cfg,
+                                                          positions)
 
 
 def _group_query(q, num_kv: int):
@@ -92,7 +144,7 @@ def _attention_chunked(q, k, v, cfg: ModelConfig, causal: bool,
     TPU program instead of an unfused S^2 intermediate.
     """
     b, s, nq, hd = q.shape
-    kv = k.shape[2]
+    kv, dv = k.shape[2], v.shape[-1]
     g = nq // kv
     scale = hd ** -0.5
     blk = min(block, s)
@@ -101,7 +153,7 @@ def _attention_chunked(q, k, v, cfg: ModelConfig, causal: bool,
     nb = s // blk
     qg = q.reshape(b, s, kv, g, hd).astype(jnp.float32)
     kb = k.reshape(b, nb, blk, kv, hd).astype(jnp.float32)
-    vb = v.reshape(b, nb, blk, kv, hd).astype(jnp.float32)
+    vb = v.reshape(b, nb, blk, kv, dv).astype(jnp.float32)
     q_pos = jnp.arange(s)
 
     def step(carry, inp):
@@ -126,12 +178,12 @@ def _attention_chunked(q, k, v, cfg: ModelConfig, causal: bool,
 
     m0 = jnp.full((b, kv, g, s), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, kv, g, s), jnp.float32)
-    a0 = jnp.zeros((b, kv, g, s, hd), jnp.float32)
+    a0 = jnp.zeros((b, kv, g, s, dv), jnp.float32)
     (m_f, l_f, acc_f), _ = jax.lax.scan(
         step, (m0, l0, a0),
         (jnp.arange(nb), jnp.moveaxis(kb, 1, 0), jnp.moveaxis(vb, 1, 0)))
     out = acc_f / jnp.maximum(l_f, 1e-30)[..., None]
-    out = jnp.moveaxis(out, -2, 1).reshape(b, s, nq, hd)
+    out = jnp.moveaxis(out, -2, 1).reshape(b, s, nq, dv)
     return out.astype(q.dtype)
 
 
@@ -152,7 +204,7 @@ def _attention_materialized(q, k, v, cfg: ModelConfig,
     scores = jnp.where(mask, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgqs,bskh->bqkgh", probs, v)
-    return out.reshape(b, s, nq, h)
+    return out.reshape(b, s, nq, v.shape[-1])
 
 
 def _attention_fused(q, k, v):
@@ -164,7 +216,7 @@ def attention(params, x, cfg: ModelConfig, positions,
               causal: bool = True) -> jnp.ndarray:
     """Attention for training/prefill; (B, S, d) -> (B, S, d)."""
     s = x.shape[1]
-    q, k, v = _project_qkv(params, x, cfg, positions)
+    q, k, v = _project(params, x, cfg, positions)
 
     if cfg.use_pallas:
         from repro.kernels import ops as kernel_ops
@@ -173,7 +225,7 @@ def attention(params, x, cfg: ModelConfig, positions,
     elif cfg.attention_impl == "chunked":
         out = _attention_chunked(q, k, v, cfg, causal)
     elif causal and not cfg.sliding_window and fused_attention.fits(
-            s, cfg.resolved_head_dim):
+            s, q.shape[-1]):
         # the platform the program lowers for picks the branch: the other
         # one is traced but never lowered
         out = jax.lax.platform_dependent(
@@ -194,13 +246,13 @@ def init_kv_cache(cfg: ModelConfig, batch: int, seq_len: int, num_layers: int,
                   dtype=jnp.bfloat16) -> Tuple[dict, dict]:
     """Cache layout (L, B, S, kv, h): seq dim shardable over the model axis
     (context-parallel decode) when kv %% model_axis != 0."""
-    h = cfg.resolved_head_dim
     seq = min(seq_len, cfg.sliding_window) if cfg.sliding_window else seq_len
-    shape = (num_layers, batch, seq, cfg.num_kv_heads, h)
+    shape = (num_layers, batch, seq, cfg.num_kv_heads)
     specs = ("layers", "batch", "kv_seq", "kv_heads", "head_dim")
+    # latent attention caches its k and v expanded, per head
     cache = {
-        "k": jnp.zeros(shape, dtype=dtype),
-        "v": jnp.zeros(shape, dtype=dtype),
+        "k": jnp.zeros(shape + (cfg.qk_head_dim,), dtype=dtype),
+        "v": jnp.zeros(shape + (cfg.resolved_v_head_dim,), dtype=dtype),
     }
     return cache, {"k": specs, "v": specs}
 
@@ -212,11 +264,11 @@ def decode_attention(params, x, cfg: ModelConfig, layer_cache, pos):
     With a sliding window the cache is a ring buffer of size ``window``.
     """
     b, _, _ = x.shape
-    h = cfg.resolved_head_dim
     k_cache, v_cache = layer_cache["k"], layer_cache["v"]
     s_cache = k_cache.shape[1]
 
-    q, k_new, v_new = _project_qkv(params, x, cfg, positions=pos[:, None])
+    q, k_new, v_new = _project(params, x, cfg, positions=pos[:, None])
+    h = q.shape[-1]
 
     slot = (pos % s_cache) if cfg.sliding_window else pos  # (B,)
     b_idx = jnp.arange(b)
@@ -238,6 +290,6 @@ def decode_attention(params, x, cfg: ModelConfig, layer_cache, pos):
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(v_cache.dtype)
     out = jnp.einsum("bkgs,bskh->bkgh", probs, v_cache)
-    out = out.reshape(b, 1, cfg.num_heads, h)
+    out = out.reshape(b, 1, cfg.num_heads, v_cache.shape[-1])
     proj = jnp.einsum("bsnh,nhd->bsd", out, params["wo"])
     return proj, {"k": k_cache, "v": v_cache}

@@ -17,7 +17,9 @@ def _round_up(x: int, m: int) -> int:
 class ModelConfig:
     # -- identity -----------------------------------------------------------
     name: str = "unnamed"
-    family: str = "dense"  # dense | moe | ssm | hybrid | vlm | audio
+    # dense | moe | mla_moe (latent attention + MoE, DeepSeek-V3 style) |
+    # ssm | hybrid | vlm | audio
+    family: str = "dense"
 
     # -- transformer trunk --------------------------------------------------
     num_layers: int = 2
@@ -33,15 +35,33 @@ class ModelConfig:
     qk_norm: bool = False
     tie_embeddings: bool = False
     rope_theta: float = 10_000.0
-    rope_type: str = "default"          # default | mrope
+    # default (rotate-half) | interleaved (adjacent pairs, as DeepSeek-V3's
+    # published code rotates) | mrope
+    rope_type: str = "default"
     mrope_sections: Tuple[int, ...] = ()  # head_dim splits for M-RoPE
     sliding_window: int = 0    # 0 -> full causal attention
 
+    # -- multi-head latent attention (DeepSeek-V2/V3), no query LoRA ---------
+    kv_lora_rank: int = 0      # >0 -> MLA: k and v from a normed latent
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0  # one rotary key of this width for all heads
+    v_head_dim: int = 0
+
     # -- MoE ----------------------------------------------------------------
-    num_experts: int = 0
+    num_experts: int = 0       # the router's width
+    experts_held: int = 0      # routed experts this chip holds; 0 -> all
     num_experts_per_tok: int = 0
+    num_shared_experts: int = 0    # one gated MLP of this many experts' width
+    # softmax: Switch aux loss | sigmoid: DeepSeek-V3 (float32 scores,
+    # top-k of score + selection bias, renormalised and scaled weights,
+    # sequence-wise balance loss)
+    router: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # 0 -> dropless: every routed token computed (sort + grouped products)
     moe_capacity_factor: float = 1.25
     router_aux_loss_coef: float = 0.001
+    first_dense_layers: int = 0    # leading dense layers of an MoE stack
+    dense_d_ff: int = 0            # their FFN width
     moe_dispatch_groups: int = 0   # >0: shard-local dispatch groups (SP/EP)
 
     # -- SSM (Mamba2 / SSD) --------------------------------------------------
@@ -84,6 +104,24 @@ class ModelConfig:
         return self.head_dim if self.head_dim else self.d_model // max(self.num_heads, 1)
 
     @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def qk_head_dim(self) -> int:
+        if self.is_mla:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
+        return self.resolved_head_dim
+
+    @property
+    def resolved_v_head_dim(self) -> int:
+        return self.v_head_dim or self.resolved_head_dim
+
+    @property
+    def resolved_experts_held(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
     def padded_vocab(self) -> int:
         return _round_up(self.vocab_size, self.vocab_pad_multiple)
 
@@ -113,12 +151,21 @@ class ModelConfig:
 
     # -- parameter counting (for roofline MODEL_FLOPS) -----------------------
     def param_count(self, active_only: bool = False) -> int:
-        """Approximate parameter count; active_only counts top-k experts."""
+        """Parameter count of the stored model (the experts this chip
+        holds); active_only counts top-k experts."""
         d, h = self.d_model, self.resolved_head_dim
         n_q, n_kv = self.num_heads, self.num_kv_heads
         embed = self.padded_vocab * d * (1 if self.tie_embeddings else 2)
 
         def attn_params() -> int:
+            if self.is_mla:
+                r = self.kv_lora_rank
+                return (d * n_q * self.qk_head_dim              # wq
+                        + d * (r + self.qk_rope_head_dim)       # wkv_a
+                        + r                                     # kv_norm
+                        + r * n_q * (self.qk_nope_head_dim
+                                     + self.resolved_v_head_dim)  # wkv_b
+                        + n_q * self.resolved_v_head_dim * d)   # wo
             p = d * (n_q * h) + 2 * d * (n_kv * h) + (n_q * h) * d
             if self.qkv_bias:
                 p += n_q * h + 2 * n_kv * h
@@ -135,18 +182,25 @@ class ModelConfig:
             out = di * d
             return in_proj + conv + out + nh + nh        # + A_log, D
 
-        per_layer = 0
+        per_layer, n_dense = 0, 0
         if self.family in ("dense", "vlm", "audio"):
             per_layer = attn_params() + dense_ffn(self.d_ff) + 2 * d
-        elif self.family == "moe":
-            n_e = self.num_experts if not active_only else self.num_experts_per_tok
-            per_layer = attn_params() + n_e * dense_ffn(self.d_ff) + d * self.num_experts + 2 * d
+        elif self.family in ("moe", "mla_moe"):
+            e = self.num_experts
+            n_e = self.num_experts_per_tok if active_only else \
+                self.resolved_experts_held
+            router = d * e + (e if self.router == "sigmoid" else 0)  # + bias
+            per_layer = (attn_params() + n_e * dense_ffn(self.d_ff) + router
+                         + dense_ffn(self.num_shared_experts * self.d_ff)
+                         + 2 * d)
+            n_dense = self.first_dense_layers
         elif self.family == "ssm":
             per_layer = ssm_params() + 2 * d
         elif self.family == "hybrid":
             per_layer = ssm_params() + 2 * d
 
-        total = embed + self.num_layers * per_layer + d
+        total = embed + (self.num_layers - n_dense) * per_layer + d
+        total += n_dense * (attn_params() + dense_ffn(self.dense_d_ff) + 2 * d)
         if self.is_hybrid and self.shared_attention:
             total += attn_params() + 2 * d  # one shared block
         if self.is_enc_dec:

@@ -78,8 +78,14 @@ def rope_frequencies(head_dim: int, theta: float):
 
 
 def apply_rope(x, positions, theta: float,
-               mrope_sections: Tuple[int, ...] = ()):
-    """x: (B, S, H, D); positions: (B, S) or (3, B, S) for M-RoPE."""
+               mrope_sections: Tuple[int, ...] = (),
+               interleaved: bool = False):
+    """x: (B, S, H, D); positions: (B, S) or (3, B, S) for M-RoPE.
+
+    The default rotates dimension i with i + D/2 (rotate-half);
+    ``interleaved`` rotates the adjacent pairs (2i, 2i+1) and keeps them in
+    place, as DeepSeek-V3's published code does (its rotate-half over the
+    de-interleaved pairs gives the same scores)."""
     d = x.shape[-1]
     freqs = rope_frequencies(d, max(theta, 1.0))  # (d/2,)
     if mrope_sections and positions.ndim == 3:
@@ -98,6 +104,11 @@ def apply_rope(x, positions, theta: float,
         angles = positions.astype(jnp.float32)[..., None] * freqs  # (B, S, d/2)
     cos = jnp.cos(angles)[..., None, :]  # (B, S, 1, d/2)
     sin = jnp.sin(angles)[..., None, :]
+    if interleaved:
+        pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], d // 2, 2)
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+        return out.reshape(x.shape).astype(x.dtype)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

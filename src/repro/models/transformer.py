@@ -1,9 +1,11 @@
-"""Decoder-only LM covering the dense / MoE / VLM families.
+"""Decoder-only LM covering the dense / MoE / MLA-MoE / VLM families.
 
 Layers are stacked on a leading ``layers`` axis and executed with
 ``jax.lax.scan`` so compile time is depth-independent; remat policy is
-selectable per config.  The same stacked layout carries the KV cache for
-decode: (L, B, S, kv, h).
+selectable per config.  An MoE model's leading dense layers
+(``first_dense_layers``) are a stack of their own, ``dense_layers``, run
+before it.  The same stacked layout carries the KV cache for decode:
+(L, B, S, kv, h), the leading dense layers first.
 """
 from __future__ import annotations
 
@@ -23,14 +25,16 @@ from repro.models import attention, layers, moe
 # ---------------------------------------------------------------------------
 
 
-def init_layer(key, cfg: ModelConfig):
+def init_layer(key, cfg: ModelConfig, dense: bool = False):
     k1, k2 = jax.random.split(key)
     p = {
         "attn_norm": layers.init_rms_norm(cfg.d_model, cfg),
         "attn": attention.init_attention(k1, cfg),
         "mlp_norm": layers.init_rms_norm(cfg.d_model, cfg),
     }
-    if cfg.is_moe:
+    if dense:
+        p["mlp"] = layers.init_mlp(k2, cfg, d_ff=cfg.dense_d_ff)
+    elif cfg.is_moe:
         p["moe"] = moe.init_moe(k2, cfg)
     else:
         p["mlp"] = layers.init_mlp(k2, cfg)
@@ -52,20 +56,33 @@ def stack_layer_params(init_one, key, num_layers: int):
 
 def init_params(key, cfg: ModelConfig):
     """Returns (params, logical_specs) with stacked layer params."""
-    k_embed, k_layers, _ = jax.random.split(key, 3)
+    k_embed, k_layers, k_dense = jax.random.split(key, 3)
+    n_dense = cfg.first_dense_layers
     stacked, layer_specs = stack_layer_params(
-        lambda k: init_layer(k, cfg), k_layers, cfg.num_layers)
+        lambda k: init_layer(k, cfg), k_layers, cfg.num_layers - n_dense)
 
     embed_params, embed_specs = layers.split_tree(layers.init_embedding(k_embed, cfg))
     fn_param, fn_spec = layers.init_rms_norm(cfg.d_model, cfg)
     params = {"embed": embed_params, "layers": stacked, "final_norm": fn_param}
     specs = {"embed": embed_specs, "layers": layer_specs, "final_norm": fn_spec}
+    if n_dense:
+        params["dense_layers"], specs["dense_layers"] = stack_layer_params(
+            lambda k: init_layer(k, cfg, dense=True), k_dense, n_dense)
     return params, specs
 
 
 # ---------------------------------------------------------------------------
 # forward (train / prefill)
 # ---------------------------------------------------------------------------
+
+
+def _ffn(layer_params, x, cfg: ModelConfig):
+    """(out, (aux_loss, expert_tokens)); a dense layer routes nothing."""
+    if "moe" in layer_params:
+        f, aux, tokens = moe.moe_ffn(layer_params["moe"], x, cfg)
+        return f, (aux, tokens)
+    return layers.mlp(layer_params["mlp"], x, cfg), (
+        jnp.float32(0), jnp.zeros((cfg.num_experts,), jnp.int32))
 
 
 def _layer_forward(layer_params, x, cfg: ModelConfig, positions):
@@ -77,12 +94,8 @@ def _layer_forward(layer_params, x, cfg: ModelConfig, positions):
     x = x + h
     with jax.named_scope("mlp"):
         normed = layers.rms_norm(x, layer_params["mlp_norm"], cfg.norm_eps)
-        if cfg.is_moe:
-            f, aux = moe.moe_ffn(layer_params["moe"], normed, cfg)
-        else:
-            f = layers.mlp(layer_params["mlp"], normed, cfg)
-            aux = jnp.float32(0)
-    return x + f, aux
+        f, routed = _ffn(layer_params, normed, cfg)
+    return x + f, routed
 
 
 def _unrolled_scan(body, carry, xs, length: int):
@@ -107,7 +120,9 @@ def _remat(fn, cfg: ModelConfig):
     if cfg.remat == "dots":
         # the fused attention's inputs, output and log-sum-exp are kept
         # too: the backward then runs neither its forward kernel nor the
-        # layout work before it again
+        # layout work before it again.  The grouped expert kernels' outputs
+        # are not dots and are recomputed: they span every (token, pick)
+        # pair, about 0.5 GB a layer for Moonlight's 2 x 4096 tokens
         policy = jax.checkpoint_policies.save_from_both_policies(
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
             jax.checkpoint_policies.save_only_these_names(
@@ -117,8 +132,11 @@ def _remat(fn, cfg: ModelConfig):
 
 
 def forward(params, x_or_tokens, cfg: ModelConfig,
-            positions: Optional[jnp.ndarray] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Embeds (if needed), runs the trunk, returns (hidden, aux_loss)."""
+            positions: Optional[jnp.ndarray] = None) -> Tuple[jnp.ndarray, dict]:
+    """Embeds (if needed), runs the trunk, returns (hidden, routing): the
+    routers' ``aux_loss`` summed over layers and, for an MoE model,
+    ``expert_tokens`` (MoE layers, experts), the tokens routed to each
+    expert."""
     if cfg.embeds_as_input:
         x = x_or_tokens.astype(jnp.dtype(cfg.compute_dtype))
     else:
@@ -133,30 +151,38 @@ def forward(params, x_or_tokens, cfg: ModelConfig,
     x = constrain(x, ("batch", "seq", None))  # SP: seq over model if enabled
 
     body = functools.partial(_layer_forward, cfg=cfg, positions=positions)
-    if cfg.scan_layers:
-        wrapped = _remat(lambda carry, lp: body(lp, carry), cfg)
+    wrapped = _remat(lambda carry, lp: body(lp, carry), cfg)
 
-        def scan_body(carry, lp):
-            new_x, aux = wrapped(carry, lp)
-            return constrain(new_x, ("batch", "seq", None)), aux
+    def scan_body(carry, lp):
+        new_x, routed = wrapped(carry, lp)
+        return constrain(new_x, ("batch", "seq", None)), routed
 
-        x, auxs = jax.lax.scan(scan_body, x, params["layers"])
-        aux = jnp.sum(auxs)
-    else:
-        aux = jnp.float32(0)
-        for i in range(cfg.num_layers):
-            lp = jax.tree.map(lambda p: p[i], params["layers"])
-            x, a = body(lp, x)
-            aux = aux + a
-    return layers.rms_norm(x, params["final_norm"], cfg.norm_eps), aux
+    aux = jnp.float32(0)
+    for stack in _stacks(params):
+        if cfg.scan_layers:
+            x, (auxs, tokens) = jax.lax.scan(scan_body, x, stack)
+        else:
+            x, (auxs, tokens) = _unrolled_scan(
+                lambda c, lp: body(lp, c), x, stack,
+                jax.tree.leaves(stack)[0].shape[0])
+        aux = aux + jnp.sum(auxs)
+    routing = {"aux_loss": aux}
+    if cfg.is_moe:
+        routing["expert_tokens"] = tokens
+    return layers.rms_norm(x, params["final_norm"], cfg.norm_eps), routing
+
+
+def _stacks(params):
+    """The layer stacks in the order they run."""
+    return [params[k] for k in ("dense_layers", "layers") if k in params]
 
 
 def loss_fn(params, batch, cfg: ModelConfig):
     """batch: {tokens|embeds, labels} -> (loss, metrics)."""
     inputs = batch["embeds"] if cfg.embeds_as_input else batch["tokens"]
-    hidden, aux = forward(params, inputs, cfg)
+    hidden, routing = forward(params, inputs, cfg)
     loss = layers.lm_loss(params, hidden, batch["labels"], cfg)
-    return loss + aux, {"loss": loss, "aux_loss": aux}
+    return loss + routing["aux_loss"], dict(routing, loss=loss)
 
 
 # ---------------------------------------------------------------------------
@@ -184,17 +210,21 @@ def decode_step(params, cache, tokens, pos, cfg: ModelConfig):
             cfg, layer_cache, pos)
         carry = carry + h
         normed = layers.rms_norm(carry, lp["mlp_norm"], cfg.norm_eps)
-        if cfg.is_moe:
-            f, _ = moe.moe_ffn(lp["moe"], normed, cfg)
-        else:
-            f = layers.mlp(lp["mlp"], normed, cfg)
+        f, _ = _ffn(lp, normed, cfg)
         return carry + f, new_lc
 
-    if cfg.scan_layers:
-        x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
-    else:
-        x, new_cache = _unrolled_scan(body, x, (params["layers"], cache),
-                                      cfg.num_layers)
+    new_caches, start = [], 0
+    for stack in _stacks(params):
+        n = jax.tree.leaves(stack)[0].shape[0]
+        part = jax.tree.map(lambda c: c[start:start + n], cache)
+        if cfg.scan_layers:
+            x, new_part = jax.lax.scan(body, x, (stack, part))
+        else:
+            x, new_part = _unrolled_scan(body, x, (stack, part), n)
+        new_caches.append(new_part)
+        start += n
+    new_cache = jax.tree.map(lambda *cs: jnp.concatenate(cs), *new_caches) \
+        if len(new_caches) > 1 else new_caches[0]
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = layers.logits_head(params["embed"], x, cfg)
     return logits, new_cache

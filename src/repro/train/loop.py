@@ -149,8 +149,18 @@ def train_loop(model: Model, pipeline: DataPipeline, cfg: LoopConfig,
 
                 if (step + 1) % cfg.log_every == 0:
                     dt = time.monotonic() - t_start
-                    print(f"step {step+1}/{cfg.total_steps} loss={loss:.4f} "
-                          f"({(step+1-start_step)/dt:.2f} steps/s)")
+                    line = (f"step {step+1}/{cfg.total_steps} "
+                            f"loss={loss:.4f} "
+                            f"({(step+1-start_step)/dt:.2f} steps/s)")
+                    if "expert_tokens" in metrics:
+                        # tokens routed to each expert of each MoE layer:
+                        # the busiest expert's load over the mean, by layer
+                        tokens = np.asarray(metrics["expert_tokens"])
+                        load = tokens.max(-1) / np.maximum(
+                            tokens.mean(-1), 1e-9)
+                        line += " expert_load_max/mean=" + ",".join(
+                            f"{x:.2f}" for x in load)
+                    print(line)
     finally:
         pipeline.stop()
         if agent is not None:

@@ -92,6 +92,38 @@ def test_fused_attention_forward_and_backward_compile_at_qwen2_widths(
         assert kernel in text
 
 
+def test_fused_attention_compiles_at_moonlight_latent_widths(one_chip):
+    # latent attention: query/key heads of 128 + 64, value heads of 128
+    b, s, h = 2, 4096, 16
+    qk, v = (b, s, h, 192), (b, s, h, 128)
+
+    def forward_and_backward(q, k, v, ct):
+        out, vjp = jax.vjp(fused_attention.causal_attention, q, k, v)
+        return out, vjp(ct)
+
+    _compile(forward_and_backward,
+             *_on(one_chip, [jax.ShapeDtypeStruct(qk, jnp.bfloat16),
+                             jax.ShapeDtypeStruct(qk, jnp.bfloat16),
+                             jax.ShapeDtypeStruct(v, jnp.bfloat16),
+                             jax.ShapeDtypeStruct(v, jnp.bfloat16)]))
+
+
+def test_grouped_matmul_compiles_at_moonlight_expert_widths(one_chip):
+    # 2 x 4096 tokens x 6 picks, 8 held experts of 2048 -> 1408
+    from repro.kernels.grouped_matmul import grouped_matmul
+
+    def forward_and_backward(x, w, sizes, ct):
+        out, vjp = jax.vjp(lambda x, w: grouped_matmul(x, w, sizes), x, w)
+        return out, vjp(ct)
+
+    text = _compile(forward_and_backward, *_on(one_chip, [
+        jax.ShapeDtypeStruct((49152, 2048), jnp.bfloat16),
+        jax.ShapeDtypeStruct((8, 2048, 1408), jnp.bfloat16),
+        jax.ShapeDtypeStruct((8,), jnp.int32),
+        jax.ShapeDtypeStruct((49152, 1408), jnp.bfloat16)])).as_text()
+    assert "gmm" in text and "tgmm" in text
+
+
 def test_rmsnorm_compiles_at_d896(one_chip):
     _compile(lambda x, w: rn.rmsnorm_fwd(x, w, interpret=False),
              *_on(one_chip, [jax.ShapeDtypeStruct((4096, 896), jnp.bfloat16),
