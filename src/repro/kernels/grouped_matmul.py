@@ -3,8 +3,11 @@ MoE layer.
 
 ``grouped_matmul(lhs, rhs, sizes)``: lhs (M, K) rows sorted by group, rhs
 (G, K, N) one matrix per held group, sizes (G,) the rows of each held
-group from the top; rows past the held groups (tokens routed to experts
-other chips hold) come out zero.  On a TPU it is the megablox grouped
+group from the top; rows past the held groups come out zero, and neither
+they nor their cotangents enter any product.  The MoE layer sizes M by
+the held experts' pairs, twice their even share, so the rows past the
+held groups are few; a step whose held pairs overflow that runs with M at
+the bound on the held pairs instead.  On a TPU it is the megablox grouped
 matmul that ships with JAX (``jax.experimental.pallas.ops.tpu.megablox``,
 forward ``gmm``, backward ``gmm`` and ``tgmm``): it visits only the tiles
 of the held groups' rows, and, being a Pallas kernel, it carries the

@@ -20,7 +20,16 @@ Dispatch:
 - dropless (``moe_capacity_factor == 0``): the (token, expert) pairs are
   sorted by expert and the held experts' rows go through grouped products
   (``repro.kernels.grouped_matmul``), so every token routed to a held
-  expert is computed.
+  expert is computed.  Each held pair takes a row of its own, and the
+  row buffers are sized by the held pairs, not by all T k pairs: M rows,
+  twice the held experts' even share in whole kernel row tiles (T k / 4
+  for a chip holding 8 of 64 experts at top-6).  A step whose held pairs
+  outnumber M runs the same dispatch with the full-size buffer instead,
+  T min(k, held) rows, which no step's held pairs exceed, so no pair is
+  ever dropped; the layer's ``dispatch_full`` says which ran.  Where M
+  would reach that size (a chip holding every expert) there is one path,
+  the full-size one.  The choice is made apart in the forward and in the
+  backward, so that neither path's intermediates are kept for the other.
 
 Expert parallelism: a layer holds the routed experts ``[lo, lo +
 experts_held)`` of the router's ``num_experts``.  It routes over all of
@@ -36,7 +45,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.grouped_matmul import grouped_matmul
+from repro.kernels.grouped_matmul import TILING, grouped_matmul
 from repro.models.config import ModelConfig
 from repro.models import layers
 
@@ -120,8 +129,10 @@ def expert_tokens(ids, num_experts: int):
 
 
 def moe_ffn(params, x, cfg: ModelConfig, lo: int = 0
-            ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """x: (B, S, d) -> (out (B, S, d), aux_loss, expert_tokens (E,)).
+            ) -> Tuple[jnp.ndarray, jnp.ndarray, dict]:
+    """x: (B, S, d) -> (out (B, S, d), aux_loss, counters): the counters
+    are ``expert_tokens`` (E,), and for the dropless dispatch
+    ``dispatch_full``, int32 1 where the full-size dispatch ran.
 
     ``lo`` is the first routed expert this layer holds (dropless dispatch).
     With ``moe_dispatch_groups = G > 0`` the token stream of the capacity
@@ -139,12 +150,13 @@ def moe_ffn(params, x, cfg: ModelConfig, lo: int = 0
         else:
             weights, ids, aux = route(params, x.reshape(b * s, d), cfg)
         weights, ids = weights.reshape(b * s, k), ids.reshape(b * s, k)
-        tokens = expert_tokens(ids, cfg.num_experts)
+        counters = {"expert_tokens": expert_tokens(ids, cfg.num_experts)}
     with jax.named_scope("experts"):
         x_flat = x.reshape(b * s, d)
         g = cfg.moe_dispatch_groups
         if cfg.moe_capacity_factor <= 0:
-            out = _dropless(params, x_flat, weights, ids, cfg, lo)
+            out, counters["dispatch_full"] = _dropless(
+                params, x_flat, weights, ids, cfg, lo)
         elif g and b % g == 0:
             from repro.parallel.context import constrain
             split = lambda a: a.reshape((g, (b // g) * s) + a.shape[1:])
@@ -158,7 +170,7 @@ def moe_ffn(params, x, cfg: ModelConfig, lo: int = 0
     if "shared" in params:
         with jax.named_scope("shared_experts"):
             out = out + layers.mlp(params["shared"], x, cfg)
-    return out, aux, tokens
+    return out, aux, counters
 
 
 def _expert_act(cfg: ModelConfig):
@@ -167,8 +179,9 @@ def _expert_act(cfg: ModelConfig):
 
 def _dropless(params, x_flat, weights, ids, cfg: ModelConfig, lo: int):
     """The held experts' part of the result for every token routed to
-    them: (T, d) -> (T, d)."""
-    t, d = x_flat.shape
+    them, and whether the full-size dispatch ran: (T, d) -> ((T, d),
+    int32)."""
+    t = x_flat.shape[0]
     k = ids.shape[-1]
     held = cfg.resolved_experts_held
     local = ids.reshape(t * k) - lo
@@ -178,57 +191,124 @@ def _dropless(params, x_flat, weights, ids, cfg: ModelConfig, lo: int):
     back = jnp.argsort(order)                   # pair -> sorted position
     sizes = jnp.sum(group[:, None] == jnp.arange(held), axis=0,
                     dtype=jnp.int32)
-    kept = mine[order][:, None]
-    # the rows past the held experts' groups are zero both ways
-    rows = jnp.where(kept, _pair_rows(x_flat, order, back, k), 0)
+    args = (x_flat, weights, (params["w_gate"], params["w_in"],
+                              params["w_out"]), order, back, sizes, mine)
     act = _expert_act(cfg)
-    h = act(grouped_matmul(rows, params["w_gate"], sizes))
-    h = h * grouped_matmul(rows, params["w_in"], sizes)
-    y = jnp.where(kept, grouped_matmul(h, params["w_out"], sizes), 0)
-    y = _permute(y, back, order).reshape(t, k, d).astype(jnp.float32)
-    out = jnp.sum(y * weights.astype(jnp.float32)[..., None], axis=1)
-    return out.astype(x_flat.dtype)
+    bound = t * min(k, held)                    # no step's held pairs exceed
+    m = _compact_rows(t, k, held, cfg.num_experts)
+    if m >= bound:
+        return _dispatch(act, bound, *args), jnp.int32(1)
+    full = jnp.sum(sizes) > m
+    return _either(act, m, bound, full, *args), full.astype(jnp.int32)
 
 
-# Row gathers by a permutation whose gradients are gathers by the inverse
-# permutation: autodiff alone would scatter-add them, which on a TPU took
-# most of the dispatch's time.
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _pair_rows(x_flat, order, back, k):
-    """The token row of each (token, pick) pair, pairs in ``order``:
-    x_flat[order // k]."""
-    return x_flat[order // k]
+def _compact_rows(t: int, k: int, held: int, num_experts: int) -> int:
+    """Twice the held experts' even share of the t k pairs, in whole row
+    tiles of the grouped kernel."""
+    tile = TILING[0]
+    return -(-2 * t * k * held // (num_experts * tile)) * tile
 
 
-def _pair_rows_fwd(x_flat, order, back, k):
-    return x_flat[order // k], back
+def _dispatch(act, m, x_flat, weights, experts, order, back, sizes, mine):
+    """The held experts' part of the result through (m, d) row buffers:
+    the first m pairs in sorted order, a row each.  The held pairs sort
+    first, so it is exact where they number at most m."""
+    t, k = weights.shape
+    w_gate, w_in, w_out = experts
+    pair = order[:m]                                # row -> pair
+    slot = jnp.where(mine, back, m).reshape(t, k)   # pair -> row; m: none
+    rows = _gather_rows(x_flat, pair, slot)
+    # the kernels leave the rows past the held groups out, and zero them
+    h = act(grouped_matmul(rows, w_gate, sizes))
+    h = h * grouped_matmul(rows, w_in, sizes)
+    y = grouped_matmul(h, w_out, sizes)
+    return _combine(y, weights, pair, slot)
 
 
-def _pair_rows_bwd(k, back, g):
-    # each token's k pairs, back in pair order, summed
-    g = g[back].reshape(-1, k, g.shape[-1])
-    return jnp.sum(g, axis=1, dtype=jnp.float32).astype(g.dtype), None, None
+# The compact dispatch or, where the held pairs outnumber its rows
+# (``full``), the full-size one.  The choice is made apart in the forward
+# and in the backward, which keep only the inputs: differentiating one
+# ``lax.cond`` keeps the residuals of both branches, at Moonlight's widths
+# 2.6 times the full-size dispatch's temporaries.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _either(act, m, bound, full, *args):
+    return jax.lax.cond(full, lambda: _dispatch(act, bound, *args),
+                        lambda: _dispatch(act, m, *args))
 
 
-_pair_rows.defvjp(_pair_rows_fwd, _pair_rows_bwd)
+def _either_fwd(act, m, bound, full, *args):
+    return _either(act, m, bound, full, *args), (full, args)
+
+
+def _either_bwd(act, m, bound, res, g):
+    full, (x_flat, weights, experts, *rest) = res
+
+    def grads(rows):
+        _, vjp = jax.vjp(lambda *a: _dispatch(act, rows, *a, *rest),
+                         x_flat, weights, experts)
+        return vjp(g)
+
+    dx, dw, de = jax.lax.cond(full, lambda: grads(bound), lambda: grads(m))
+    return (None, dx, dw, de) + (None,) * len(rest)
+
+
+_either.defvjp(_either_fwd, _either_bwd)
+
+
+# The row gather and the combine, each the other's transpose, so that no
+# gradient is a scatter-add, which on a TPU took most of the dispatch's
+# time: every row belongs to one (token, pick) pair, ``pair`` maps rows to
+# pairs and ``slot`` (T, k) pairs to rows, past the rows for a pair that
+# has none.
+
+@jax.custom_vjp
+def _gather_rows(x_flat, pair, slot):
+    """The token row of each row's pair: x_flat[pair // k]."""
+    return x_flat[pair // slot.shape[1]]
+
+
+def _gather_rows_fwd(x_flat, pair, slot):
+    return _gather_rows(x_flat, pair, slot), slot
+
+
+def _gather_rows_bwd(slot, g):
+    # each token's rows, summed
+    rows = g.at[slot].get(mode="fill", fill_value=0)
+    return jnp.sum(rows, axis=1, dtype=jnp.float32).astype(g.dtype), None, None
+
+
+_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
 
 
 @jax.custom_vjp
-def _permute(x, perm, inverse):
-    """x[perm], ``inverse`` the inverse permutation of ``perm``."""
-    return x[perm]
+def _combine(y, weights, pair, slot):
+    """Each token's rows weighted by its routing weights and summed, in
+    float32: (m, d), (T, k) -> (T, d)."""
+    rows = y.at[slot].get(mode="fill", fill_value=0).astype(jnp.float32)
+    out = jnp.sum(rows * weights.astype(jnp.float32)[..., None], axis=1)
+    return out.astype(y.dtype)
 
 
-def _permute_fwd(x, perm, inverse):
-    return x[perm], inverse
+def _combine_fwd(y, weights, pair, slot):
+    return _combine(y, weights, pair, slot), (y, weights, pair, slot)
 
 
-def _permute_bwd(inverse, g):
-    return g[inverse], None, None
+def _combine_bwd(res, g):
+    y, weights, pair, slot = res
+    k = weights.shape[-1]
+    g_rows = g[pair // k].astype(jnp.float32)       # each row's token's
+    w_rows = weights.reshape(-1)[pair].astype(jnp.float32)
+    # the rows of other chips' experts get cotangents too: the grouped
+    # kernels leave them out
+    dy = (g_rows * w_rows[:, None]).astype(y.dtype)
+    # the weights' gradient by row, back to (T, k) as scalars
+    dw_rows = jnp.sum(g_rows * y.astype(jnp.float32), axis=-1)
+    dw = dw_rows.at[slot].get(mode="fill", fill_value=0)
+    return dy, dw.astype(weights.dtype), None, None
 
 
-_permute.defvjp(_permute_fwd, _permute_bwd)
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def _capacity_dispatch(params, x_flat, weights, ids, cfg: ModelConfig):

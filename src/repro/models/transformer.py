@@ -77,12 +77,11 @@ def init_params(key, cfg: ModelConfig):
 
 
 def _ffn(layer_params, x, cfg: ModelConfig):
-    """(out, (aux_loss, expert_tokens)); a dense layer routes nothing."""
+    """(out, (aux_loss, counters)); a dense layer routes nothing."""
     if "moe" in layer_params:
-        f, aux, tokens = moe.moe_ffn(layer_params["moe"], x, cfg)
-        return f, (aux, tokens)
-    return layers.mlp(layer_params["mlp"], x, cfg), (
-        jnp.float32(0), jnp.zeros((cfg.num_experts,), jnp.int32))
+        f, aux, counters = moe.moe_ffn(layer_params["moe"], x, cfg)
+        return f, (aux, counters)
+    return layers.mlp(layer_params["mlp"], x, cfg), (jnp.float32(0), {})
 
 
 def _layer_forward(layer_params, x, cfg: ModelConfig, positions):
@@ -121,8 +120,7 @@ def _remat(fn, cfg: ModelConfig):
         # the fused attention's inputs, output and log-sum-exp are kept
         # too: the backward then runs neither its forward kernel nor the
         # layout work before it again.  The grouped expert kernels' outputs
-        # are not dots and are recomputed: they span every (token, pick)
-        # pair, about 0.5 GB a layer for Moonlight's 2 x 4096 tokens
+        # are not dots: the dropless dispatch's backward recomputes them
         policy = jax.checkpoint_policies.save_from_both_policies(
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
             jax.checkpoint_policies.save_only_these_names(
@@ -136,7 +134,8 @@ def forward(params, x_or_tokens, cfg: ModelConfig,
     """Embeds (if needed), runs the trunk, returns (hidden, routing): the
     routers' ``aux_loss`` summed over layers and, for an MoE model,
     ``expert_tokens`` (MoE layers, experts), the tokens routed to each
-    expert."""
+    expert, and for a dropless one ``dispatch_full`` (MoE layers,), 1
+    where a layer ran the full-size dispatch."""
     if cfg.embeds_as_input:
         x = x_or_tokens.astype(jnp.dtype(cfg.compute_dtype))
     else:
@@ -157,18 +156,16 @@ def forward(params, x_or_tokens, cfg: ModelConfig,
         new_x, routed = wrapped(carry, lp)
         return constrain(new_x, ("batch", "seq", None)), routed
 
-    aux = jnp.float32(0)
+    routing = {"aux_loss": jnp.float32(0)}
     for stack in _stacks(params):
         if cfg.scan_layers:
-            x, (auxs, tokens) = jax.lax.scan(scan_body, x, stack)
+            x, (auxs, counters) = jax.lax.scan(scan_body, x, stack)
         else:
-            x, (auxs, tokens) = _unrolled_scan(
+            x, (auxs, counters) = _unrolled_scan(
                 lambda c, lp: body(lp, c), x, stack,
                 jax.tree.leaves(stack)[0].shape[0])
-        aux = aux + jnp.sum(auxs)
-    routing = {"aux_loss": aux}
-    if cfg.is_moe:
-        routing["expert_tokens"] = tokens
+        routing["aux_loss"] = routing["aux_loss"] + jnp.sum(auxs)
+        routing.update(counters)
     return layers.rms_norm(x, params["final_norm"], cfg.norm_eps), routing
 
 

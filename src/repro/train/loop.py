@@ -160,6 +160,10 @@ def train_loop(model: Model, pipeline: DataPipeline, cfg: LoopConfig,
                             tokens.mean(-1), 1e-9)
                         line += " expert_load_max/mean=" + ",".join(
                             f"{x:.2f}" for x in load)
+                    if "dispatch_full" in metrics:
+                        # 1 where a dropless layer ran the full-size dispatch
+                        line += " dispatch_full=" + ",".join(map(
+                            str, np.asarray(metrics["dispatch_full"])))
                     print(line)
     finally:
         pipeline.stop()

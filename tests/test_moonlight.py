@@ -1,10 +1,11 @@
 """Moonlight-16B-A3B's mechanisms in the program, at tiny sizes: latent
 attention through the fused kernels (interpret mode) and through the
 decode cache, the grouped expert kernels (interpret mode), DeepSeek-V3
-routing with dropless dispatch over the experts a chip holds, the
-per-expert token counter, and the parameter count of the published model
-and of one chip's share."""
+routing with dropless dispatch over the experts a chip holds (compact and
+full-size), the per-expert token and full-size dispatch counters, and the
+parameter count of the published model and of one chip's share."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -64,7 +65,7 @@ def test_kernel_takes_a_narrower_value_head(s, nq, nkv, dqk, dv):
 def test_grouped_kernel_matches_ragged_dot_and_zeroes_other_rows():
     """The megablox kernels (interpret mode) against ``ragged_dot``: the
     held groups' rows, forward and both gradients; the rows past them, of
-    experts other chips hold, zero."""
+    experts other chips hold, zero, and their cotangents left out."""
     from repro.kernels.grouped_matmul import grouped_matmul
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     x = jax.random.normal(ks[0], (1024, 256), jnp.bfloat16)
@@ -77,12 +78,17 @@ def test_grouped_kernel_matches_ragged_dot_and_zeroes_other_rows():
             x, w, sizes, interpret=interpret), x, w)
         return (out,) + vjp(ct)
 
-    for name, f, r in zip(("out", "dx", "dw"), run(True), run(False)):
+    got = {interpret: run(interpret) for interpret in (True, False)}
+    for name, f, r in zip(("out", "dx", "dw"), got[True], got[False]):
         assert f.shape == r.shape and f.dtype == r.dtype, name
         assert _rel(f, r) < 1e-2, name
-    out, dx, _ = run(True)
+    out, dx, _ = got[True]
     assert not np.any(np.asarray(out[437:], np.float32))
     assert not np.any(np.asarray(dx[437:], np.float32))
+    # nor do those rows' cotangents reach the experts' gradient
+    ct = ct.at[437:].set(0)
+    for interpret in (True, False):
+        np.testing.assert_array_equal(run(interpret)[2], got[interpret][2])
 
 
 def test_latent_attention_lowers_to_the_kernels_on_a_tpu():
@@ -142,20 +148,30 @@ def _held_part_by_hand(params, x, cfg, lo):
     return out
 
 
-def test_no_token_routed_to_a_held_expert_is_dropped():
+def _skew_onto_the_first_experts(params, cfg):
+    k, e = cfg.num_experts_per_tok, cfg.num_experts
+    return dict(params, router_bias=jnp.where(jnp.arange(e) < k, 10.0, 0.0))
+
+
+@pytest.mark.parametrize("s", [
+    16,     # the compact buffer would be no smaller than the full-size one
+    256,    # the held pairs overflow the compact buffer's 512 rows
+])
+def test_no_token_routed_to_a_held_expert_is_dropped(s):
     """A router skewed so that every token picks the first experts: the
     two held ones get every token of the batch, which a capacity of 1.25
-    times the mean would cut to a fraction."""
+    times the mean would cut to a fraction, and which overflows the
+    compact dispatch; the full-size one runs."""
     cfg = _f32(configs.tiny("moonlight-16b-a3b-ep8"))
     k, e = cfg.num_experts_per_tok, cfg.num_experts
-    params = _moe_params(cfg)
-    params["router_bias"] = jnp.where(jnp.arange(e) < k, 10.0, 0.0)
-    x = jax.random.normal(jax.random.PRNGKey(2), (2, 16, cfg.d_model))
+    params = _skew_onto_the_first_experts(_moe_params(cfg), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, s, cfg.d_model))
     with jax.default_matmul_precision("highest"):
-        out, _, tokens = moe.moe_ffn(params, x, cfg, lo=0)
+        out, _, counters = moe.moe_ffn(params, x, cfg, lo=0)
         shared = layers.mlp(params["shared"], x, cfg)
         want = _held_part_by_hand(params, x, cfg, 0)
-    assert tokens.tolist() == [32] * k + [0] * (e - k)
+    assert counters["expert_tokens"].tolist() == [2 * s] * k + [0] * (e - k)
+    assert int(counters["dispatch_full"]) == 1
     np.testing.assert_allclose(out - shared, want, rtol=1e-5, atol=1e-5)
     # the selection bias steers the choice, not the weights
     w, ids, _ = moe.route_sigmoid(params, x, cfg)
@@ -164,6 +180,55 @@ def test_no_token_routed_to_a_held_expert_is_dropped():
     np.testing.assert_allclose(
         w, picked / picked.sum(-1, keepdims=True) * cfg.routed_scaling_factor,
         rtol=1e-5)
+
+
+@pytest.mark.parametrize("kernel", ["ragged_dot", "megablox"])
+@pytest.mark.parametrize("routing,full", [
+    ("initialised", 0),         # 269 of the 1024 pairs held, 512 rows
+    ("skewed", 1),              # all 1024 held
+])
+def test_compact_dispatch_matches_the_full_size_one(monkeypatch, kernel,
+                                                    routing, full):
+    """2 x 256 tokens at top-4 with 2 of 16 experts held: the compact
+    buffer has 512 rows, the full-size one 1024.  Forward and gradients
+    with respect to the tokens, the routing weights and the experts, in
+    float32, against the full-size dispatch alone."""
+    from repro.kernels.grouped_matmul import grouped_matmul
+    if kernel == "megablox":
+        monkeypatch.setattr(moe, "grouped_matmul", functools.partial(
+            grouped_matmul, interpret=True))
+    cfg = _f32(configs.tiny("moonlight-16b-a3b-ep8"))
+    params = _moe_params(cfg)
+    if routing == "skewed":
+        params = _skew_onto_the_first_experts(params, cfg)
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 256, cfg.d_model))
+    weights, ids, _ = moe.route_sigmoid(params, x, cfg)
+    k = cfg.num_experts_per_tok
+    x, weights, ids = (x.reshape(512, -1), weights.reshape(512, k),
+                       ids.reshape(512, k))
+    experts = {n: params[n] for n in ("w_gate", "w_in", "w_out")}
+    ct = jax.random.normal(jax.random.PRNGKey(3), x.shape)
+
+    def run():
+        with jax.default_matmul_precision("highest"):
+            (out, ran_full), vjp = jax.vjp(
+                lambda x, w, e: moe._dropless(e, x, w, ids, cfg, 0),
+                x, weights, experts)
+            no_ct = np.zeros((), jax.dtypes.float0)
+            return ran_full, (out,) + vjp((ct, no_ct))
+
+    ran_full, got = run()
+    assert int(ran_full) == full
+    # the full-size dispatch alone, as where the compact buffer is no
+    # smaller than it
+    monkeypatch.setattr(moe, "_compact_rows", lambda *a: 1 << 30)
+    ran_full, want = run()
+    assert int(ran_full) == 1
+    for name, g, w in zip(("out", "x", "weights", "w_gate", "w_in",
+                           "w_out"), jax.tree.leaves(got),
+                          jax.tree.leaves(want)):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        assert _rel(g, w) < 1e-5, name
 
 
 def test_expert_tokens_count_every_routed_token_of_every_moe_layer():
@@ -183,6 +248,8 @@ def test_expert_tokens_count_every_routed_token_of_every_moe_layer():
     assert tokens.shape == (moe_layers, cfg.num_experts)
     assert tokens.sum(axis=1).tolist() == [b * s * cfg.num_experts_per_tok] \
         * moe_layers
+    full = np.asarray(metrics["dispatch_full"])
+    assert full.dtype == np.int32 and full.shape == (moe_layers,)
     assert float(metrics["aux_loss"]) > 0
 
 
@@ -198,10 +265,14 @@ def test_the_loop_logs_the_expert_load_at_log_steps(capsys):
     lines = [l for l in capsys.readouterr().out.splitlines()
              if l.startswith("step ")]
     assert len(lines) == 2
+    moe_layers = cfg.num_layers - cfg.first_dense_layers
     for line in lines:
-        loads = line.split("expert_load_max/mean=")[1].split(",")
-        assert len(loads) == cfg.num_layers - cfg.first_dense_layers
+        loads = line.split("expert_load_max/mean=")[1].split()[0].split(",")
+        assert len(loads) == moe_layers
         assert all(float(x) >= 1.0 for x in loads)
+        # at 2 x 16 tokens the full-size dispatch is the only one
+        full = line.split("dispatch_full=")[1].split()[0].split(",")
+        assert full == ["1"] * moe_layers
 
 
 def _leaf_count(cfg) -> int:

@@ -13,6 +13,7 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -122,6 +123,47 @@ def test_grouped_matmul_compiles_at_moonlight_expert_widths(one_chip):
         jax.ShapeDtypeStruct((8,), jnp.int32),
         jax.ShapeDtypeStruct((49152, 1408), jnp.bfloat16)])).as_text()
     assert "gmm" in text and "tgmm" in text
+
+
+def test_moonlight_dispatch_keeps_one_paths_temporaries(one_chip,
+                                                       monkeypatch):
+    # one MoE layer's routed part, forward and backward, at the cell's
+    # widths: 2 x 4096 tokens, top-6, experts 0-7 of 64, 2048 -> 1408
+    from repro.models import moe
+    cfg = configs.get("moonlight-16b-a3b-ep8")
+    t, k, d, f, held = 8192, 6, 2048, 1408, 8
+
+    def forward_and_backward(x, weights, ids, experts, ct):
+        (out, full), vjp = jax.vjp(
+            lambda x, w, e: moe._dropless(e, x, w, ids, cfg, 0),
+            x, weights, experts)
+        return out, full, vjp((ct, np.zeros((), jax.dtypes.float0)))
+
+    args = _on(one_chip, [
+        jax.ShapeDtypeStruct((t, d), jnp.bfloat16),
+        jax.ShapeDtypeStruct((t, k), jnp.float32),
+        jax.ShapeDtypeStruct((t, k), jnp.int32),
+        {"w_gate": jax.ShapeDtypeStruct((held, d, f), jnp.bfloat16),
+         "w_in": jax.ShapeDtypeStruct((held, d, f), jnp.bfloat16),
+         "w_out": jax.ShapeDtypeStruct((held, f, d), jnp.bfloat16)},
+        jax.ShapeDtypeStruct((t, d), jnp.bfloat16)])
+
+    def temp():
+        # a new function each time: jit's cache does not see the patch
+        compiled = _compile(lambda *a: forward_and_backward(*a), *args)
+        assert "tgmm" in compiled.as_text()
+        return compiled.memory_analysis().temp_size_in_bytes
+
+    either = temp()
+    # the full-size dispatch alone, as where the compact buffer is no
+    # smaller than it
+    monkeypatch.setattr(moe, "_compact_rows", lambda *a: 1 << 40)
+    full = temp()
+    # differentiating one lax.cond over both keeps both paths' residuals,
+    # 2.6 times the full-size dispatch's temporaries; the choice made
+    # apart in the forward and the backward keeps one path's, and the
+    # conditionals' own buffers (0.3 %)
+    assert either <= 1.01 * full, (either, full)
 
 
 def test_rmsnorm_compiles_at_d896(one_chip):
