@@ -1,10 +1,10 @@
 """Scenario-registry matrix — coverage beyond the five §5.4 cases.
 
 Drives every scenario registered in ``repro.core.scenarios`` through all
-five service paths (legacy batch, streaming object, wire-encoded
-columnar, sharded front-end, hierarchical pod tier over wire v3
-sessions) via ``simcluster.run_scenario_matrix`` and reports, per
-scenario, the wall time over the five paths and whether every path
+four service paths (dataclass ingest, wire-encoded columnar, sharded
+front-end, hierarchical pod tier over wire v3 sessions) via
+``simcluster.run_scenario_matrix`` and reports, per scenario, the wall
+time over the four paths and whether every path
 produced the expected diagnosis.  The run *asserts* full coverage: one
 MISS anywhere fails the benchmark (and CI's bench gate).
 """

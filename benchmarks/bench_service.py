@@ -1,17 +1,16 @@
-"""Service-scale benchmark: streaming vs. legacy ingestion/analysis paths.
+"""Service-scale benchmark: the streaming service's ingest and cycle cost.
 
-Three measurements back the tentpole claims of the sharded streaming
-refactor:
+Four measurements back the bounded-state claims of the streaming service:
 
-  1. ingest throughput (profiles/sec), streaming vs. legacy, single group;
+  1. ingest throughput (profiles/sec), single group;
   2. steady-state process() cycle latency while a uniform regression is
      *active* (the temporal path re-checks the group flame graph every
      cycle), at growing totals of ingested profiles.  Acceptance: the
-     streaming path's cycle latency grows sub-linearly in total ingested
-     profiles — its state is the live window, not the history.  The
-     retained-state counter (iteration-time entries) is reported for both
-     paths: ring-buffered vs. grow-forever;
-  3. a 1,024-rank fleet (32 groups x 32 ranks) with concurrent
+     cycle latency grows sub-linearly in total ingested profiles — the
+     state is the live window, not the history — and the retained
+     iteration-time entries stay within the ring buffer;
+  3. encoded columnar batches vs. per-dataclass ingest of one fleet;
+  4. a 1,024-rank fleet (32 groups x 32 ranks) with concurrent
      heterogeneous faults driven into an 8-shard ShardedService, reporting
      sustained fleet ingest rate, cycle time, and that both injected root
      causes are diagnosed.
@@ -30,8 +29,8 @@ from repro.core.sharded import ShardedService
 CHECKPOINTS = (40, 160, 640)            # iterations (8 ranks => x8 profiles)
 
 
-def _ingest_throughput(streaming: bool, iters: int = 120) -> float:
-    svc = CentralService(window=50, streaming=streaming)
+def _ingest_throughput(iters: int = 120) -> float:
+    svc = CentralService(window=50)
     cl = sc.SimCluster(n_ranks=8, seed=1, samples_per_iter=100)
     profiles = [p for _ in range(iters) for p in cl.step()]
     t0 = time.monotonic()
@@ -41,11 +40,10 @@ def _ingest_throughput(streaming: bool, iters: int = 120) -> float:
     return len(profiles) / dt
 
 
-def _steady_cycle_latency(streaming: bool
-                          ) -> Tuple[List[float], CentralService]:
+def _steady_cycle_latency() -> Tuple[List[float], CentralService]:
     """Mean process() wall time (us) over the last 10 one-iteration cycles
     before each checkpoint, with a logging regression active throughout."""
-    svc = CentralService(window=50, streaming=streaming)
+    svc = CentralService(window=50)
     cl = sc.SimCluster(n_ranks=8, seed=2, samples_per_iter=100)
     cl.run(svc, 20, process_every=10)        # healthy baseline bootstrap
     cl.add_fault(sc.logging_overhead(start=20))
@@ -98,40 +96,29 @@ def _fleet(n_groups: int = 32, ranks_per_group: int = 32, iters: int = 25,
 
 
 def run(out_lines: List[str]) -> Dict[str, float]:
-    out_lines.append("# streaming-vs-legacy service paths + 1k-rank fleet")
+    out_lines.append("# streaming service cycle + 1k-rank fleet")
     res: Dict[str, float] = {}
 
-    tp_new = _ingest_throughput(streaming=True)
-    tp_old = _ingest_throughput(streaming=False)
-    out_lines.append(f"service_ingest_streaming,{1e6/tp_new:.1f},"
-                     f"{tp_new:.0f}_profiles_per_s")
-    out_lines.append(f"service_ingest_legacy,{1e6/tp_old:.1f},"
-                     f"{tp_old:.0f}_profiles_per_s")
-    res["ingest_streaming_per_s"] = tp_new
-    res["ingest_legacy_per_s"] = tp_old
+    tp = _ingest_throughput()
+    out_lines.append(f"service_ingest_streaming,{1e6/tp:.1f},"
+                     f"{tp:.0f}_profiles_per_s")
+    res["ingest_streaming_per_s"] = tp
 
-    lat_new, svc_new = _steady_cycle_latency(streaming=True)
-    lat_old, svc_old = _steady_cycle_latency(streaming=False)
-    for tag, lat in (("streaming", lat_new), ("legacy", lat_old)):
-        for n, us in zip(CHECKPOINTS, lat):
-            out_lines.append(f"service_process_{tag}_{n}iters,{us:.0f},us")
+    lat, svc = _steady_cycle_latency()
+    for n, us in zip(CHECKPOINTS, lat):
+        out_lines.append(f"service_process_streaming_{n}iters,{us:.0f},us")
     # 16x more ingested profiles from first to last checkpoint: the
-    # streaming cycle must grow sub-linearly (bounded state)
-    growth_new = lat_new[-1] / max(lat_new[0], 1e-9)
-    growth_old = lat_old[-1] / max(lat_old[0], 1e-9)
+    # cycle must grow sub-linearly (bounded state)
+    growth = lat[-1] / max(lat[0], 1e-9)
     data_growth = CHECKPOINTS[-1] / CHECKPOINTS[0]
-    out_lines.append(f"service_process_growth_streaming,0,{growth_new:.2f}x")
-    out_lines.append(f"service_process_growth_legacy,0,{growth_old:.2f}x")
-    out_lines.append(
-        f"service_state_iter_entries,0,"
-        f"{svc_new.stats()['iter_time_entries']:.0f}_streaming_vs_"
-        f"{svc_old.stats()['iter_time_entries']:.0f}_legacy")
-    res["process_growth_streaming"] = growth_new
-    res["process_growth_legacy"] = growth_old
-    assert growth_new < data_growth / 2, (
-        f"streaming process() grew {growth_new:.1f}x over a "
+    entries = svc.stats()["iter_time_entries"]
+    out_lines.append(f"service_process_growth_streaming,0,{growth:.2f}x")
+    out_lines.append(f"service_state_iter_entries,0,{entries:.0f}")
+    res["process_growth_streaming"] = growth
+    assert growth < data_growth / 2, (
+        f"streaming process() grew {growth:.1f}x over a "
         f"{data_growth:.0f}x history increase — bounded state is broken")
-    assert svc_new.stats()["iter_time_entries"] <= svc_new.window, \
+    assert entries <= svc.window, \
         "streaming iteration-time history must be ring-buffered"
 
     # encoded columnar batches vs. per-dataclass ingest on one identical

@@ -15,8 +15,9 @@ table.  Prints ``name,us_per_call,derived`` CSV lines per the contract.
                        cascade localization cycles
   bench_cases        — §5.4  (five end-to-end case studies) + Fig 2
   bench_scenarios    — full scenario-registry matrix (every registered
-                       scenario x legacy/streaming/columnar/sharded)
-  bench_service      — streaming-vs-legacy service + 1k-rank sharded fleet
+                       scenario x streaming/columnar/sharded/pod)
+  bench_service      — streaming service cycle growth + 1k-rank sharded
+                       fleet
   bench_query        — query-plane gates: 32-reader ingest-regression
                        guard (< 1.2x cycle slowdown) + sustained-ingest
                        query throughput/p99 floors

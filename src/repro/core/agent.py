@@ -136,8 +136,8 @@ class NodeAgent:
         re-buffered on failure is the already-interned *columnar* view,
         so a retry re-encodes the identical bytes without re-interning
         or allocating new columns.  Services exposing only
-        ``ingest_batch`` (legacy sharded front-ends) get the dataclass
-        batch in one call; plain services get per-profile ``ingest``.
+        ``ingest_batch`` get the dataclass batch in one call; plain
+        services get per-profile ``ingest``.
         """
         with self._lock:
             batch, self._buffer = self._buffer, []

@@ -37,8 +37,8 @@ Invariants:
   * Blame totals are invariant under rank relabeling and profile
     ingestion order (hypothesis-tested).
   * Where no cascade exists, localization is the identity: every alert
-    resolves to its own (group, rank) and the service's verdicts equal
-    the pre-attribution pairwise path (equivalence-tested).
+    resolves to its own (group, rank), which is pairwise-diffed as the
+    root (tested).
 
 A note on cross-group identity: ranks are matched across groups by
 rank id, so fleets must use globally unique rank ids for bridge ranks
@@ -400,9 +400,8 @@ def iteration_timelines_naive(
 @dataclasses.dataclass(frozen=True)
 class Localization:
     """One localized root: where the blame chain terminated.  ``alert``
-    is the root group's own alert when it raised one (the no-cascade
-    case reduces to exactly the pre-attribution pairwise input), else
-    the triggering downstream alert."""
+    is the root group's own alert when it raised one (always, in the
+    no-cascade case), else the triggering downstream alert."""
     alert: StragglerAlert
     root_group: str
     root_rank: int

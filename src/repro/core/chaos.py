@@ -11,19 +11,16 @@ storm replays bit-identically — on the same service path or across all
 of them — from nothing but ``(seed, layout, links)``.
 
 :class:`ChaosRunner` drives one schedule into one service path (the
-same five paths ``run_scenario_matrix`` exercises) and scores the
+same paths ``run_scenario_matrix`` exercises) and scores the
 outcome: which true roots were localized, how often emitted verdicts
 flipped causes, and the full event-tuple stream for cross-path
 equality assertions.  ``benchmarks/bench_chaos.py`` gates a pinned
 storm on exactly these scores.
 
 Storm faults draw from the *stackless* scenario subset by default
-(kernel/OS/entry-delay effects only).  Stack-rewriting injectors are
-excluded from cross-path storms on purpose: the streaming path's
-decayed flame graphs and the legacy path's per-cycle rebuilds converge
-differently in the cycles after a mid-run ``remove_fault``, so a
-flapping stack fault would make legacy-vs-streaming event equality
-depend on decay half-lives rather than on diagnosis correctness.
+(kernel/OS/entry-delay effects only), so a storm scores localization
+and flap damping rather than how fast decayed flame graphs forget a
+stack fault after a mid-run ``remove_fault``.
 """
 from __future__ import annotations
 
@@ -348,8 +345,6 @@ class ChaosRunner:
         from repro.core.pod import MultiProcPodService, PodTierService
         from repro.core.service import CentralService
         from repro.core.sharded import ShardedService
-        if path == "legacy":
-            return CentralService(streaming=False, **kwargs)
         if path in ("streaming", "columnar"):
             return CentralService(**kwargs)
         if path == "sharded":

@@ -154,7 +154,7 @@ class VerdictDamper:
         verdict retires and the next diagnosis starts fresh.
 
     Determinism: decisions depend only on the proposal stream, so the
-    legacy/streaming/columnar/sharded/pod paths (which feed identical
+    streaming/columnar/sharded/pod paths (which feed identical
     streams per group) damp identically — the scenario-matrix
     event-for-event equality holds with damping on.
     """

@@ -163,13 +163,11 @@ class PodAggregator:
         dense and indexed by the shared stack id space, so the merge is
         one vector add per rank plus a single ``nonzero`` at the end —
         no per-rank sparsification (32k ``nonzero`` calls per cycle was
-        a quarter of the facade's collection time).  Legacy dict-backed
-        graphs (non-columnar ingest) have no dense vector and are
-        skipped — the pod tier fronts the columnar upload path."""
+        a quarter of the facade's collection time)."""
         acc = None
         for fg in self.engine._rank_fg.values():
-            vec = getattr(fg, "_vec", None)
-            if vec is None or not vec.shape[0]:
+            vec = fg._vec
+            if not vec.shape[0]:
                 continue
             if acc is None or acc.shape[0] < vec.shape[0]:
                 grown = np.zeros(vec.shape[0])

@@ -9,23 +9,22 @@ Pipeline per ingested batch:
      blame across overlapping communication groups to the root (node,
      rank), then layered diagnosis (GPU diff -> CPU diff -> OS diff)
      at the root only; victim groups get cascade_blame_exported events.
-     ``attribution=False`` preserves the pre-attribution pairwise path
-     (every alerting rank diffed), equivalence-tested where no cascade
-     exists.  No alert but iter-time regression? -> temporal baseline.
+     No alert but iter-time regression? -> temporal baseline.
   4. every diagnosis becomes a DiagnosticEvent with a category matching the
      paper's Fig 2 taxonomy (gpu_hardware | os_interference | network |
      software) and a wall-clock diagnosis latency.
 
-Streaming architecture (the default, ``streaming=True``): all analysis
-state is *bounded and maintained incrementally at ingest time* — ring-
-buffered iteration-time windows and exponentially-decayed per-(group, rank)
-flame graphs — so one ``process()`` cycle costs O(groups + alerts), not
-O(total ingested samples).  That is what lets a single service instance sit
-under a fleet-scale ingest stream the way the paper's regional deployments
-do (§5: 80k+ GPUs, minutes-not-days).  ``streaming=False`` preserves the
-original batch shape (grow-forever history, per-cycle
-``FlameGraph.from_samples`` rebuilds) for the old-vs-new benchmark in
-``benchmarks/bench_service.py``.
+Streaming architecture: all analysis state is *bounded and maintained
+incrementally at ingest time* — ring-buffered iteration-time windows and
+exponentially-decayed per-(group, rank) flame graphs — so one
+``process()`` cycle costs O(groups + alerts), not O(total ingested
+samples).  That is what lets a single service instance sit under a
+fleet-scale ingest stream the way the paper's regional deployments do
+(§5: 80k+ GPUs, minutes-not-days).
+
+One representation inside: an ``IterationProfile`` dataclass is converted
+to a ``ColumnarProfile`` over the service's tables when it enters
+``ingest``; everything after the door is columnar.
 
 Invariants:
 
@@ -33,7 +32,7 @@ Invariants:
     diagnoses whether ingested as an ``IterationProfile`` dataclass, a
     native ``ColumnarProfile``, or a wire-encoded batch via
     ``ingest_encoded`` — asserted for every registered scenario across
-    the legacy/streaming/columnar/sharded paths
+    the streaming/columnar/sharded/pod paths
     (``simcluster.run_scenario_matrix``).
   * Registry immutability after service start: the service pins a frozen
     ``ScenarioRegistry.snapshot()`` at construction (``self.rules``);
@@ -54,16 +53,11 @@ import numpy as np
 
 from repro.core.attribution import (CASCADE_EXPORT_CAUSE, CascadeExport,
                                     Localization, TimelineBuilder,
-                                    iteration_timelines,
-                                    iteration_timelines_naive,
-                                    localize_cascades)
+                                    iteration_timelines, localize_cascades)
 from repro.core.baseline import BaselineStore, compare_to_baseline
-from repro.core.collective.instances import (separate_instance_indices,
-                                             separate_instances)
+from repro.core.collective.instances import separate_instance_indices
 from repro.core.diffdiag import Verdict, VerdictDamper, diagnose
-from repro.core.events import (CollectiveEvent, IterationProfile,
-                               ProfileBatch)
-from repro.core.flamegraph import FlameGraph
+from repro.core.events import IterationProfile
 from repro.core.query import (BlameRoot, DiagnosisQueryAPI, EventLog,
                               FleetSnapshot, GroupView, RankHistory,
                               blame_roots_from)
@@ -73,7 +67,8 @@ from repro.core.spans import span
 from repro.core.straggler import StragglerAlert, StragglerDetector
 from repro.core.symbols.repo import SymbolRepository
 from repro.core.trace import (ColumnFlameGraph, ColumnarProfile, RemapCache,
-                              TraceTables, decode_batch, remap_profile)
+                              TraceTables, decode_batch, profile_to_columnar,
+                              remap_profile)
 from repro.core.waterline import CPUWaterline
 
 __all__ = ["CATEGORY_BY_CAUSE", "LOG_SOP_RULES", "DiagnosticEvent",
@@ -91,6 +86,17 @@ LOG_SOP_RULES: List[Tuple[str, str]] = [
     ("checkpoint write failed", "ckpt_storage"),
     ("Loss is NaN", "loss_nan"),
 ]
+
+# temporal path: a group's recent iteration time this far above its
+# baseline triggers a flame-graph comparison against the baseline
+ITER_REGRESSION = 0.05
+# verdict flap damping (diffdiag.VerdictDamper): a cause flip on a
+# standing verdict needs FLAP_CONFIRM consecutive cycles; contested or
+# absent standings decay by FLAP_DECAY per cycle and retire after
+# FLAP_RETIRE absent cycles
+FLAP_CONFIRM = 2
+FLAP_DECAY = 0.7
+FLAP_RETIRE = 4
 
 
 @dataclasses.dataclass
@@ -134,21 +140,15 @@ class DiagnosticEvent:
 class CentralService(DiagnosisQueryAPI):
     def __init__(self, window: int = 100, k: float = 2.0,
                  baseline_delta: float = 0.005,
-                 iter_regression: float = 0.05,
                  robust_detector: bool = False,
-                 streaming: bool = True,
                  fg_window: int = 16,
                  group_ttl_s: Optional[float] = 3600.0,
                  registry: Optional[ScenarioRegistry] = None,
-                 attribution: bool = True,
                  min_root_lateness: float = 1e-4,
                  chips_per_node: int = 8,
                  retain: int = 512,
                  publish_stride: int = 1,
-                 flap_damping: bool = True,
-                 flap_confirm: int = 2,
-                 flap_decay: float = 0.7,
-                 flap_retire: int = 4):
+                 flap_damping: bool = True):
         self.symbol_repo = SymbolRepository()
         self.baselines = BaselineStore()
         # rule-set immutability after service start: pin a frozen snapshot
@@ -171,8 +171,6 @@ class CentralService(DiagnosisQueryAPI):
                                  names=self.tables.strings))
         self.window = window
         self.baseline_delta = baseline_delta
-        self.iter_regression = iter_regression
-        self.streaming = streaming
         # effective flame-graph memory in iterations: decay gamma such
         # that weight halves roughly every fg_window*ln2 iterations
         self.fg_window = max(2, fg_window)
@@ -181,19 +179,13 @@ class CentralService(DiagnosisQueryAPI):
         self._counts: Dict[str, int] = defaultdict(int)
         # latest per (group, rank) profile for differential diagnosis
         # (kernel timings + OS signals; bounded: one entry per live rank)
-        self._latest: Dict[Tuple[str, int], IterationProfile] = {}
-        # streaming: decayed per-(group, rank) flame graphs, merged at
-        # ingest; legacy: rebuilt from raw samples every process() cycle
-        self._rank_fg: Dict[Tuple[str, int], FlameGraph] = {}
-        # iteration-time history: ring buffer (streaming) or grow-forever
-        # list (legacy — the pre-refactor behaviour kept for benchmarks)
-        if streaming:
-            self._group_iter_time: Dict[str, Deque[float]] = defaultdict(
-                lambda: deque(maxlen=window))
-        else:
-            self._group_iter_time = defaultdict(list)
-        self._pending_collectives: List[CollectiveEvent] = []
-        # columnar profiles defer collective materialization to process()
+        self._latest: Dict[Tuple[str, int], ColumnarProfile] = {}
+        # decayed per-(group, rank) flame graphs, merged at ingest
+        self._rank_fg: Dict[Tuple[str, int], ColumnFlameGraph] = {}
+        # iteration-time history: one ring buffer per group
+        self._group_iter_time: Dict[str, Deque[float]] = defaultdict(
+            lambda: deque(maxlen=window))
+        # profiles with collectives defer materialization to process()
         self._pending_coll_profiles: List[ColumnarProfile] = []
         self._job_by_group: Dict[str, str] = {}
         # group -> live rank set, so per-group lookups never scan the
@@ -205,15 +197,10 @@ class CentralService(DiagnosisQueryAPI):
         self._last_ingest: Dict[str, float] = {}
         self.groups_evicted = 0
         self.ingested = 0
-        # attribution=True routes alerts through cascade localization
-        # (repro.core.attribution) so only blame *roots* are pairwise-
-        # diffed; False preserves the pre-attribution pairwise path
-        # (equivalence-tested where no cascade exists)
-        self.attribution = attribution
         # significance floor for cascade localization: alerts below it
         # are windowed jitter (the same 100us threshold the network
         # fallback uses for "timing says slow"), not incidents worth a
-        # root diagnosis — the legacy pairwise path keeps reporting them
+        # root diagnosis
         self.min_root_lateness = min_root_lateness
         # node topology for provenance (rank -> node in cascade
         # evidence); mirror it in MitigationPlanner(chips_per_node=...)
@@ -226,8 +213,8 @@ class CentralService(DiagnosisQueryAPI):
         # unchanged, so single-incident scenarios emit exactly as
         # without damping (the scenario matrix holds with it on).
         self.damper: Optional[VerdictDamper] = (
-            VerdictDamper(confirm=flap_confirm, decay=flap_decay,
-                          retire_after=flap_retire)
+            VerdictDamper(confirm=FLAP_CONFIRM, decay=FLAP_DECAY,
+                          retire_after=FLAP_RETIRE)
             if flap_damping else None)
         self._tl_builder = TimelineBuilder(self.tables)
         # per-collective blame edges drained from the detector on the
@@ -273,8 +260,13 @@ class CentralService(DiagnosisQueryAPI):
         return remap_profile(profile, self._remaps.get(profile.tables))
 
     def ingest(self, profile, job_id: str = "job-0") -> None:
-        """Ingest one per-rank iteration — an ``IterationProfile``
-        (boundary schema) or a ``ColumnarProfile`` (hot path)."""
+        """Ingest one per-rank iteration — a ``ColumnarProfile`` (hot
+        path) or an ``IterationProfile`` (boundary schema), which is
+        converted to columns over the service's tables here, at the door."""
+        if isinstance(profile, IterationProfile):
+            profile = profile_to_columnar(profile, self.tables)
+        elif profile.tables is not self.tables:
+            profile = self._adopt(profile)
         self.ingested += 1
         g = profile.group_id
         self._job_by_group[g] = job_id
@@ -286,45 +278,17 @@ class CentralService(DiagnosisQueryAPI):
             hist = self._history[(g, profile.rank)] = \
                 RankHistory(self.retain)
         hist.append(profile.iteration, profile.iter_time)
-        if isinstance(profile, ColumnarProfile):
-            if profile.tables is not self.tables:
-                profile = self._adopt(profile)
-            self._latest[(g, profile.rank)] = profile
-            if profile.coll_op.shape[0]:
-                self._pending_coll_profiles.append(profile)
-            ids, fracs = profile.function_fraction_sparse()
-            self.waterlines[g].observe_sparse(profile.rank, ids, fracs)
-            if self.streaming:
-                key = (g, profile.rank)
-                acc = self._rank_fg.get(key)
-                if acc is None:
-                    acc = self._rank_fg[key] = ColumnFlameGraph(self.tables)
-                acc.decay(self._fg_decay)
-                if isinstance(acc, ColumnFlameGraph):
-                    acc.add_sid_weights(profile.stack_id,
-                                        profile.stack_weight)
-                else:           # rank switched representations mid-stream
-                    acc.add_rows(zip(profile.stack_id.tolist(),
-                                     profile.stack_weight.tolist()),
-                                 self.tables.stack_tuple)
-        else:
-            self._latest[(g, profile.rank)] = profile
-            self._pending_collectives.extend(profile.collectives)
-            fg = FlameGraph.from_samples(profile.cpu_samples)
-            self.waterlines[g].observe(profile.rank, fg)
-            if self.streaming:
-                key = (g, profile.rank)
-                acc = self._rank_fg.get(key)
-                if acc is None:
-                    acc = self._rank_fg[key] = FlameGraph()
-                acc.decay(self._fg_decay)
-                if isinstance(acc, ColumnFlameGraph):
-                    # rank switched representations mid-stream: intern
-                    acc.add_id_rows(
-                        (self.tables.intern_stack(st), w)
-                        for st, w in fg.counts.items())
-                else:
-                    acc.add_graph(fg)
+        key = (g, profile.rank)
+        self._latest[key] = profile
+        if profile.coll_op.shape[0]:
+            self._pending_coll_profiles.append(profile)
+        ids, fracs = profile.function_fraction_sparse()
+        self.waterlines[g].observe_sparse(profile.rank, ids, fracs)
+        acc = self._rank_fg.get(key)
+        if acc is None:
+            acc = self._rank_fg[key] = ColumnFlameGraph(self.tables)
+        acc.decay(self._fg_decay)
+        acc.add_sid_weights(profile.stack_id, profile.stack_weight)
 
     def ingest_batch(self, batch) -> int:
         """One agent upload (§4's 30 s cycle) — a ``ProfileBatch`` or
@@ -421,44 +385,23 @@ class CentralService(DiagnosisQueryAPI):
 
     # -- analysis cycle (the "processed within minutes" loop) ----------------
     def _materialize_collectives(self) -> None:
-        """Deferred columnar collectives -> instance separation ->
-        detector (blame-edge accumulation), once per cycle.
-
-        All-columnar cycles (the production ingest shape) take the
-        array fast path: channels are keyed by interned (group, op) ids
-        straight off the wire columns and observed through the
-        detector's array methods — zero ``CollectiveEvent`` objects.
-        At 32k ranks the object route's per-event dataclass churn was
-        ~4 s of every analysis cycle.  A cycle that also holds
-        dataclass-ingested collectives falls back to the object route
-        for everything, so mixed representations stay on one ordering.
-        """
-        if self._pending_coll_profiles and not self._pending_collectives:
-            self._materialize_columnar_collectives()
-            self._pending_coll_profiles = []
-            return
-        if self._pending_coll_profiles:
-            for p in self._pending_coll_profiles:
-                self._pending_collectives.extend(p.collective_events())
-            self._pending_coll_profiles = []
-        if self._pending_collectives:
-            for inst in separate_instances(self._pending_collectives):
-                self.detector.observe_instance(inst)
-            self._pending_collectives = []
-
-    def _materialize_columnar_collectives(self) -> None:
-        """Array twin of the object route, state-for-state identical:
-        channels form in the same first-seen order, events within a
-        channel scan in the same stable entry order, instance members
-        rank-sort the same way, and the final cross-channel pass sorts
-        by the same min-raw-entry key — so the detector's windows, sums
-        and blame edges come out in exactly the object route's order.
+        """Deferred collectives -> instance separation -> detector
+        (blame-edge accumulation), once per cycle, with zero
+        ``CollectiveEvent`` objects: channels are keyed by interned
+        (group, op) ids straight off the columns and observed through
+        the detector's array methods.  The order matches
+        ``collective.separate_instances`` over the same events: channels
+        form in first-seen order, events within a channel scan in stable
+        entry order, instance members rank-sort the same way, and the
+        final cross-channel pass sorts by the min-raw-entry key.
 
         Channel grouping is one stable argsort over the concatenated
-        wire columns (profile order is the scan order), not a per-event
+        columns (profile order is the scan order), not a per-event
         Python walk — at 32k ranks the dict-of-lists channel build was
         ~15% of the analysis cycle."""
-        P = self._pending_coll_profiles
+        P, self._pending_coll_profiles = self._pending_coll_profiles, []
+        if not P:
+            return
         lens = np.fromiter((p.coll_entry.shape[0] for p in P),
                            np.int64, len(P))
         if not int(lens.sum()):
@@ -475,7 +418,7 @@ class CentralService(DiagnosisQueryAPI):
         by_key = np.argsort(key, kind="stable")     # scan order within key
         bounds = np.concatenate(([0], np.cumsum(np.bincount(inv))))
         insts = []
-        # channels in first-seen order, like the object route's dict
+        # channels in first-seen order
         for ci in np.argsort(first, kind="stable").tolist():
             sl = by_key[bounds[ci]:bounds[ci + 1]]
             ea, xa, rlist = ens[sl], exs[sl], rks[sl].tolist()
@@ -538,35 +481,24 @@ class CentralService(DiagnosisQueryAPI):
         t0 = time.monotonic()
         new_events: List[DiagnosticEvent] = []
         flagged: set = set()
-        if self.attribution:
-            # 1. alerts -> cascade localization -> diagnose roots only
-            alerts, summaries = self.collect_cycle(t0)
-            locs, exports = localize_cascades(alerts, summaries)
-            # retain this cycle's blame-root pointers for audit() walks
-            # (stamped with the epoch the coming publish will carry)
-            self._blame_roots.update(
-                blame_roots_from(locs, exports, self._epoch + 1))
-            for loc in locs:
-                flagged.add(loc.root_group)
-                flagged.update(loc.affected_groups)
-                ev = self._diagnose_root(loc, t0)
-                if ev:
-                    new_events.append(ev)
-            for exp in exports:
-                flagged.add(exp.group_id)
-                ev = self._export_event(exp, t0)
-                if ev:
-                    new_events.append(ev)
-        else:
-            # pre-attribution pairwise path: diff every alerting rank
-            self._evict_idle_groups(t0)
-            self._materialize_collectives()
-            alerts = self.detector.check()
-            for alert in alerts[:8]:  # bounded per cycle
-                flagged.add(alert.group_id)
-                ev = self._diagnose_straggler(alert, t0)
-                if ev:
-                    new_events.append(ev)
+        # 1. alerts -> cascade localization -> diagnose roots only
+        alerts, summaries = self.collect_cycle(t0)
+        locs, exports = localize_cascades(alerts, summaries)
+        # retain this cycle's blame-root pointers for audit() walks
+        # (stamped with the epoch the coming publish will carry)
+        self._blame_roots.update(
+            blame_roots_from(locs, exports, self._epoch + 1))
+        for loc in locs:
+            flagged.add(loc.root_group)
+            flagged.update(loc.affected_groups)
+            ev = self._diagnose_root(loc, t0)
+            if ev:
+                new_events.append(ev)
+        for exp in exports:
+            flagged.add(exp.group_id)
+            ev = self._export_event(exp, t0)
+            if ev:
+                new_events.append(ev)
         # 2. uniform-degradation path
         new_events.extend(self._temporal_cycle(flagged, t0))
         if self.damper is not None:
@@ -584,31 +516,11 @@ class CentralService(DiagnosisQueryAPI):
         return new_events
 
     # -- straggler path ---------------------------------------------------------
-    @staticmethod
-    def _profile_flamegraph(p) -> FlameGraph:
-        if isinstance(p, ColumnarProfile):
-            return p.flamegraph()
-        return FlameGraph.from_samples(p.cpu_samples)
-
-    @staticmethod
-    def _profile_kernels(p):
-        """What ``gpu_diff`` aggregates: the columnar profile itself (it
-        carries interned kernel columns) or the dataclass event list."""
-        return p if isinstance(p, ColumnarProfile) else p.kernel_events
-
-    def _rank_flamegraph(self, g: str, rank: int) -> FlameGraph:
-        """Windowed CPU profile of one rank: the decayed incremental graph
-        (streaming) or a fresh rebuild from the latest raw samples (legacy)."""
-        if self.streaming:
-            fg = self._rank_fg.get((g, rank))
-            return fg if fg is not None else FlameGraph()
-        return self._profile_flamegraph(self._latest[(g, rank)])
-
     def _diagnose_pair(self, g: str, rank: int, alert: StragglerAlert,
                        t0: float) -> Optional[DiagnosticEvent]:
-        """Layered pairwise diff of ``rank`` against a healthy peer in
-        its group — shared by the legacy per-alert path and the
-        attribution path (which only ever calls it at a blame root)."""
+        """Layered pairwise diff of a blame root ``rank`` against a
+        healthy peer in its group: kernel columns of the latest profiles,
+        then the decayed per-rank flame graphs, then OS signals."""
         ranks = sorted(self._group_ranks.get(g, ()))
         if len(ranks) < 2 or rank not in ranks:
             return None
@@ -618,9 +530,7 @@ class CentralService(DiagnosisQueryAPI):
         hp = self._latest[(g, healthy)]
 
         verdict = diagnose(
-            self._profile_kernels(sp), self._profile_kernels(hp),
-            self._rank_flamegraph(g, rank),
-            self._rank_flamegraph(g, healthy),
+            sp, hp, self._rank_fg[(g, rank)], self._rank_fg[(g, healthy)],
             sp.os_signals, hp.os_signals, registry=self.rules)
         if verdict.layer == "inconclusive" and alert.lateness > 1e-4:
             # timing says slow but no layer diverges -> network path (§7)
@@ -637,17 +547,12 @@ class CentralService(DiagnosisQueryAPI):
             diagnosis_latency_s=time.monotonic() - t0,
             evidence={"alert": dataclasses.asdict(alert)}))
 
-    def _diagnose_straggler(self, alert: StragglerAlert,
-                            t0: float) -> Optional[DiagnosticEvent]:
-        return self._diagnose_pair(alert.group_id, alert.rank, alert, t0)
-
     def _group_timelines(self, g: str):
         """Blame timelines of one group's latest iteration, computed
         over every rank's latest profile (instance starts need the whole
-        group's aligned entries).  Empty when representations are mixed
-        or fewer than two ranks share the latest iteration — matching a
-        stale iteration against current peers would read as a
-        full-iteration wait."""
+        group's aligned entries).  Empty when fewer than two ranks share
+        the latest iteration — matching a stale iteration against current
+        peers would read as a full-iteration wait."""
         ranks = sorted(self._group_ranks.get(g, ()))
         profiles = [p for p in (self._latest.get((g, r)) for r in ranks)
                     if p is not None]
@@ -657,14 +562,9 @@ class CentralService(DiagnosisQueryAPI):
         profiles = [p for p in profiles if p.iteration == latest_iter]
         if len(profiles) < 2:
             return []
-        skew = self.detector.aligner.skew
-        if all(isinstance(p, ColumnarProfile) for p in profiles):
-            tls, _ = iteration_timelines(profiles, skew=skew,
-                                         builder=self._tl_builder)
-        elif all(isinstance(p, IterationProfile) for p in profiles):
-            tls, _ = iteration_timelines_naive(profiles, skew=skew)
-        else:
-            return []
+        tls, _ = iteration_timelines(
+            profiles, skew=self.detector.aligner.skew,
+            builder=self._tl_builder)
         return tls
 
     def _rank_timeline(self, g: str, rank: int):
@@ -743,7 +643,7 @@ class CentralService(DiagnosisQueryAPI):
             if fg is not None:
                 self.baselines.save(job, g, fg, iter_time=recent)
             return None
-        if recent < base_time * (1 + self.iter_regression):
+        if recent < base_time * (1 + ITER_REGRESSION):
             return None
         baseline_fg = self.baselines.get(job, g)
         current_fg = self._group_flamegraph(g)
@@ -770,33 +670,13 @@ class CentralService(DiagnosisQueryAPI):
             detected_at=t0, diagnosis_latency_s=time.monotonic() - t0,
             evidence={"iter_time": (base_time, recent)}))
 
-    def _group_flamegraph(self, g: str) -> Optional[FlameGraph]:
-        if self.streaming:
-            ranks = self._group_ranks.get(g)
-            if not ranks:
-                return None
-            fgs = [fg for fg in (self._rank_fg.get((g, r)) for r in ranks)
-                   if fg is not None]
-            if not fgs:
-                return None
-            if all(isinstance(f, ColumnFlameGraph) for f in fgs):
-                out = ColumnFlameGraph(self.tables)
-                for f in fgs:
-                    out.add_graph(f)
-            else:
-                out = FlameGraph()
-                for f in fgs:
-                    out.add_graph(f.to_flamegraph()
-                                  if isinstance(f, ColumnFlameGraph) else f)
-            return out if out.total else None
-        fgs = [self._profile_flamegraph(p)
-               for (gg, _r), p in self._latest.items() if gg == g]
-        if not fgs:
-            return None
-        out = fgs[0]
-        for f in fgs[1:]:
-            out = out.merge(f)
-        return out
+    def _group_flamegraph(self, g: str) -> Optional[ColumnFlameGraph]:
+        """The group's decayed per-rank flame graphs summed; None while
+        the group holds no live stack weight."""
+        out = ColumnFlameGraph(self.tables)
+        for r in self._group_ranks.get(g, ()):
+            out.add_graph(self._rank_fg[(g, r)])
+        return out if out.total else None
 
     # -- queryable diagnosis plane (publication side) ------------------------------
     def _record_timelines(self) -> None:

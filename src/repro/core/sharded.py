@@ -149,32 +149,13 @@ class ShardedService(DiagnosisQueryAPI):
     def process(self) -> List[DiagnosticEvent]:
         """Run one analysis cycle fleet-wide.
 
-        With attribution enabled (the shard default), the cycle splits:
-        every shard runs its *collection* half (instance separation,
-        blame accumulation, alerts + group blame summaries), the facade
-        merges those summaries and runs cascade localization ONCE over
-        the whole fleet — blame chains cross shard boundaries even
-        though per-group diagnosis state never does — and then each
-        root/export event is diagnosed and recorded on the shard owning
-        its group.  With ``attribution=False`` shards process
-        independently as before (the pre-attribution pairwise path)."""
-        if not self.shards[0].attribution:
-            t0 = time.monotonic()
-            if self.parallel and self.n_shards > 1:
-                with ThreadPoolExecutor(max_workers=self.n_shards) as ex:
-                    results = list(ex.map(lambda s: s.process(),
-                                          self.shards))
-            else:
-                results = [s.process() for s in self.shards]
-            merged: List[DiagnosticEvent] = []
-            for evs in results:
-                merged.extend(evs)
-            merged.sort(key=lambda e: e.detected_at)
-            # each shard's process() already published its own snapshot;
-            # merge them into the facade's fleet view
-            self._publish_merged(t0)
-            return merged
-
+        The cycle splits: every shard runs its *collection* half
+        (instance separation, blame accumulation, alerts + group blame
+        summaries), the facade merges those summaries and runs cascade
+        localization ONCE over the whole fleet — blame chains cross
+        shard boundaries even though per-group diagnosis state never
+        does — and then each root/export event is diagnosed and
+        recorded on the shard owning its group."""
         t0 = time.monotonic()
         alerts, summaries = self._collect_fleet(t0)
         locs, exports = localize_cascades(alerts, summaries)

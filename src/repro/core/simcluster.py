@@ -13,7 +13,7 @@ production scenario is one factory function plus a registry entry
 (``repro.core.scenarios``) — no simulator edits.  The factories below
 cover the paper's five §5.4 case studies plus six further production
 incidents; :func:`run_scenario_matrix` drives every registered scenario
-through the legacy, streaming, columnar and sharded service paths and
+through the streaming, columnar, sharded and pod service paths and
 checks the expected diagnosis.
 
 Wall-clock here is simulated (the cluster "runs" at arbitrary speed), so
@@ -922,13 +922,12 @@ def fleet_slos(cluster, margin: float = 0.2, window: int = 8,
 # scenario matrix: every registered scenario x every service path
 # ---------------------------------------------------------------------------
 
-#: The five ingest/analysis paths a diagnosis must survive unchanged:
-#: legacy batch (streaming=False), streaming object ingest, wire-encoded
-#: columnar upload, the group-partitioned sharded front-end, and the
-#: hierarchical pod tier (wire v3 dictionary-delta session uploads into
-#: ``PodTierService``'s two-level collection tree).
-SERVICE_PATHS: Tuple[str, ...] = (
-    "legacy", "streaming", "columnar", "sharded", "pod")
+#: The four ingest/analysis paths a diagnosis must survive unchanged:
+#: dataclass ingest (converted to columns at the service's door),
+#: wire-encoded columnar upload, the group-partitioned sharded
+#: front-end, and the hierarchical pod tier (wire v3 dictionary-delta
+#: session uploads into ``PodTierService``'s two-level collection tree).
+SERVICE_PATHS: Tuple[str, ...] = ("streaming", "columnar", "sharded", "pod")
 
 
 @dataclasses.dataclass
@@ -964,9 +963,7 @@ def _drive_scenario(scenario, path: str, *, n_ranks: int, seed: int,
 
     kwargs = dict(window=window, robust_detector=scenario.robust_detector,
                   registry=registry)
-    if path == "legacy":
-        svc = CentralService(streaming=False, **kwargs)
-    elif path in ("streaming", "columnar"):
+    if path in ("streaming", "columnar"):
         svc = CentralService(**kwargs)
     elif path == "sharded":
         svc = ShardedService(n_shards=n_shards, **kwargs)

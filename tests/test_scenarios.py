@@ -2,8 +2,8 @@
 snapshot immutability, rule-driven diffdiag behaviour, wire-format
 version negotiation for the extended OS counters, and the scenario
 matrix — every registered scenario must produce its expected diagnosis
-on all five service paths (legacy, streaming, columnar, sharded, pod
-tier over wire v3 sessions)."""
+on all four service paths (streaming, columnar, sharded, pod tier over
+wire v3 sessions)."""
 import dataclasses
 
 import pytest
@@ -309,8 +309,8 @@ def test_scenario_diagnoses_on_all_service_paths(name):
     """The acceptance gate, generalized from the old hand-enumerated
     five-case equivalence tests: each registered scenario's first
     diagnosis is the expected root cause (and straggler rank, where
-    pinned) on the legacy, streaming, columnar, sharded and pod paths
-    alike — and all five paths agree event for event."""
+    pinned) on the streaming, columnar, sharded and pod paths alike —
+    and all four paths agree event for event."""
     scen = _REGISTRY.get(name)
     results = run_scenario_matrix(scenarios=[scen], strict=True)
     per_path = results[name]

@@ -1,12 +1,15 @@
 """Streaming-service invariants: bounded state, incremental flame graphs,
-ring-buffered windows, and agreement with the legacy batch path."""
+ring-buffered windows, and dataclass uploads converted to columns at the
+service's door."""
 import pytest
 
 from repro.core import simcluster as sc
 from repro.core.baseline import BaselineStore
+from repro.core.events import ProfileBatch
 from repro.core.flamegraph import FlameGraph
 from repro.core.service import CentralService
 from repro.core.sharded import ShardedService, shard_of
+from repro.core.trace import encode_batch, to_columnar
 
 
 # -- FlameGraph streaming primitives ----------------------------------------
@@ -67,32 +70,36 @@ def test_streaming_state_is_bounded():
         assert len(fg.counts) < 64
 
 
-def test_legacy_mode_keeps_full_history():
-    svc = CentralService(window=50, streaming=False)
-    cl = sc.SimCluster(n_ranks=4, seed=0, samples_per_iter=50)
-    cl.run(svc, 120, process_every=40)
-    # grow-forever list: one entry per ingested profile (4 ranks x 120)
-    assert svc.stats()["iter_time_entries"] == 120 * 4
-
-
-@pytest.mark.parametrize("fault,robust", [
-    (sc.thermal_throttle(0, start=30), False),
-    (sc.nic_softirq(4, start=30), False),
-    (sc.logging_overhead(start=30), False),
+@pytest.mark.parametrize("fault", [
+    sc.thermal_throttle(0, start=30),
+    sc.nic_softirq(4, start=30),
+    sc.logging_overhead(start=30),
 ])
-def test_streaming_matches_legacy_diagnoses(fault, robust):
-    import copy
-    results = []
-    for streaming in (True, False):
-        svc = CentralService(window=50, robust_detector=robust,
-                             streaming=streaming)
-        cl = sc.SimCluster(n_ranks=8, seed=7)
-        cl.run(svc, 30)
-        cl.add_fault(copy.deepcopy(fault))
-        cl.run(svc, 60)
-        results.append([(e.root_cause, e.category, e.straggler_rank)
-                        for e in svc.events])
-    assert results[0] and results[0][0] == results[1][0]
+def test_dataclass_ingest_matches_encoded_columnar(fault):
+    """The door: one simulated stream, fed to one service as dataclasses
+    and to another as wire-encoded columnar batches, diagnoses the same
+    events with the same verdict confidences."""
+    by_obj, by_wire = CentralService(window=50), CentralService(window=50)
+    cl = sc.SimCluster(n_ranks=8, seed=7)
+    for it in range(90):
+        if it == 30:
+            cl.add_fault(fault)
+        profiles = cl.step()
+        for p in profiles:
+            by_obj.ingest(p)
+        by_wire.ingest_encoded(encode_batch(to_columnar(
+            ProfileBatch("job-0", profiles))))
+        if (it + 1) % 10 == 0:
+            by_obj.process()
+            by_wire.process()
+
+    def tuples(svc):
+        return [(e.group_id, e.root_cause, e.category, e.straggler_rank)
+                for e in svc.events]
+
+    assert by_obj.events and tuples(by_obj) == tuples(by_wire)
+    assert [e.verdict.confidence for e in by_obj.events] == pytest.approx(
+        [e.verdict.confidence for e in by_wire.events])
 
 
 def test_event_counts_incremental():

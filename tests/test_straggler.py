@@ -159,3 +159,29 @@ def test_ring_windows_match_bruteforce_after_wrap():
     det.forget_group("g")
     assert det.blame_summary("g") is None
     assert det.aligner.skew(members[0], "g") == 0.0
+
+
+def test_service_collectives_match_object_separation():
+    """The service separates instances straight off the collective
+    columns; its detector must end in the state of the object route —
+    ``separate_instances`` over the same events, one
+    ``observe_instance`` per instance — edge for edge, window for
+    window."""
+    from repro.core import simcluster as sc
+    from repro.core.service import CentralService
+    cl = sc.SimCluster(n_ranks=8, seed=3)
+    cl.add_fault(sc.nic_softirq(4))
+    svc = CentralService(window=50)
+    ref = StragglerDetector(window=50)
+    events = []
+    for _ in range(20):
+        for p in cl.step():
+            svc.ingest(p)
+            events.extend(p.collectives)
+    for inst in separate_instances(events):
+        ref.observe_instance(inst)
+    svc.collect_cycle()
+    assert svc.last_edges and svc.last_edges == ref.drain_edges()
+    ours, theirs = svc.last_summaries, ref.blame_summaries()
+    assert {g: s.as_dict() for g, s in ours.items()} == \
+        {g: s.as_dict() for g, s in theirs.items()}

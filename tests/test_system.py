@@ -78,8 +78,8 @@ def test_real_profiler_collects_from_training(tiny_model):
 
 
 # ---------------------------------------------------------------------------
-# cross-path equivalence over the whole scenario registry (legacy batch,
-# streaming object, wire-encoded columnar and sharded paths, event for
+# cross-path equivalence over the whole scenario registry (dataclass
+# ingest, wire-encoded columnar, sharded and pod paths, event for
 # event) lives in tests/test_scenarios.py — one run of
 # simcluster.run_scenario_matrix asserts both the expected verdicts and
 # path-equality, replacing the hand-enumerated five-case tests that used
